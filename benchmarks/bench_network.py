@@ -410,7 +410,9 @@ def run_executor_scaling():
     # on the quiet-machine value instead of whichever leg got unlucky
     for _ in range(2):
         for shards in (1, 2, 4):
-            env = dict(os.environ)
+            # host-device legs by design: pinned to the CPU so a child can
+            # never contend for a chip this parent process already holds
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             env["XLA_FLAGS"] = \
                 f"--xla_force_host_platform_device_count={shards}"
             cmd = [sys.executable, "-m", "benchmarks.bench_network",
@@ -683,8 +685,9 @@ def main(fast: bool = True, downlink: bool = False,
     if executor == "mesh" and len(jax.devices()) < 2 \
             and not os.environ.get("_BENCH_MESH_CHILD"):
         # re-exec with forced host devices so the mesh cells see a real
-        # mesh (the trace/obs flags ride along through sys.argv)
-        env = dict(os.environ)
+        # mesh (the trace/obs flags ride along through sys.argv); pinned to
+        # the CPU — this parent already initialised JAX and may hold a chip
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4 " \
             + env.get("XLA_FLAGS", "")
         env["_BENCH_MESH_CHILD"] = "1"

@@ -83,6 +83,7 @@ from repro.checkpointing import save_checkpoint
 from repro.core.quantizer import PQConfig
 from repro.core.split import tree_bits
 from repro.data.synthetic import make_federated_image_data
+from repro.launch.cache import enable_compile_cache
 from repro.federated import (DEFAULT_CHAOS, AsyncBuffer, Deadline,
                              DropSlowestK, FederatedTrainer, FullSync,
                              lognormal_fleet, mobile_fleet)
@@ -150,6 +151,7 @@ def main():
                          "--emit-trace path with .jsonl swapped for "
                          ".perfetto.json)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.emit_trace:
         obs.configure(run="femnist_example", meta={

@@ -14,11 +14,13 @@ from repro.core.correction import quantize_with_correction
 from repro.core.quantizer import PQConfig, quantize
 from repro.core.fedlite import TrainState, make_train_step
 from repro.data.synthetic import make_federated_image_data
+from repro.launch.cache import enable_compile_cache
 from repro.models.paper_models import FemnistCNN
 from repro.optim import sgd
 
 
 def main():
+    enable_compile_cache()
     # --- 1. the quantizer by itself -----------------------------------------
     z = jax.random.normal(jax.random.PRNGKey(0), (20, 9216))  # B=20, d=9216
     pq = PQConfig(num_subvectors=1152, num_clusters=2)        # paper's 490x pt

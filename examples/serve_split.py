@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ARCH_IDS, get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.launch.specs import make_model
 
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--no-compress", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, smoke=True)
     if cfg.family in ("vlm",):

@@ -20,6 +20,7 @@ from repro.configs.base import ARCH_IDS, get_arch
 from repro.core.fedlite import TrainState, comm_report, make_train_step
 from repro.core.quantizer import PQConfig
 from repro.data.synthetic import make_federated_lm_data
+from repro.launch.cache import enable_compile_cache
 from repro.launch.specs import make_model
 from repro.optim import get_optimizer
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--lam", type=float, default=1e-4)
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, smoke=True)
     if cfg.family in ("vlm", "audio"):

@@ -246,7 +246,6 @@ def make_mesh_step(model, optimizer: Optimizer, mesh, *,
     the unmasked entries). One optimizer update per call, on the replicated
     combined gradient — parameters never shard over ``clients``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.ctx import CLIENTS_AXIS
@@ -303,12 +302,12 @@ def make_mesh_step(model, optimizer: Optimizer, mesh, *,
         # prefix specs: every client-major pytree (batches, keys, cut state,
         # per-client losses/metrics) shards its LEADING axis over `clients`;
         # params and the psum'd gradient stay replicated
-        gsum, losses, metrics = shard_map(
+        gsum, losses, metrics = jax.shard_map(
             shard_local, mesh=mesh,
             in_specs=(P(), P(CLIENTS_AXIS), P(CLIENTS_AXIS), P(CLIENTS_AXIS),
                       P(CLIENTS_AXIS), P(CLIENTS_AXIS)),
             out_specs=(P(), P(CLIENTS_AXIS), P(CLIENTS_AXIS)),
-            check_rep=False)(state.params, batches, weights, mask, keys,
+            check_vma=False)(state.params, batches, weights, mask, keys,
                              cut_state)
         ghat = jax.tree.map(
             lambda g, p: g.astype(p.dtype), gsum, state.params)

@@ -64,6 +64,8 @@ import jax.numpy as jnp
 
 from repro import obs
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class KMeansResult(NamedTuple):
     centroids: jax.Array  # (L, D)
@@ -102,7 +104,9 @@ def _pad_chunks(x: jax.Array, chunk: int):
 def _assign_jnp(x: jax.Array, centroids: jax.Array) -> jax.Array:
     """codes[i] = argmin_l ‖x_i − c_l‖².  x: (n, D), centroids: (L, D)."""
     # ‖x‖² is constant across l — only the cross term and ‖c‖² matter.
-    scores = (2.0 * (x @ centroids.T)
+    # HIGHEST: full-f32 distances on TPU too (XLA's default there is one
+    # bf16 pass), as the Pallas kernels compute them
+    scores = (2.0 * jnp.matmul(x, centroids.T, precision=HIGHEST)
               - jnp.sum(centroids * centroids, axis=-1)[None, :])
     return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
@@ -143,15 +147,14 @@ def _assign_pallas(x: jax.Array, centroids: jax.Array) -> jax.Array:
 
 def _encode_pallas(x: jax.Array, centroids: jax.Array, chunk: int):
     from repro.kernels import ops
-    block_n = min(512, max(chunk, 8))
-    zt, resid, codes = ops.pq_quantize(x, centroids, block_n=block_n)
+    zt, resid, codes = ops.pq_quantize(x, centroids)
     return zt.astype(jnp.float32), resid, codes
 
 
 def _assign_dist_pallas(x: jax.Array, centroids: jax.Array, chunk: int):
     # the assign kernel already emits distances — no z̃ HBM write
     from repro.kernels import ops
-    return ops.kmeans_assign(x, centroids, block_n=min(512, max(chunk, 8)))
+    return ops.kmeans_assign(x, centroids)
 
 
 def _update_scan(assign, x, weights, centroids, chunk):
@@ -171,7 +174,7 @@ def _update_scan(assign, x, weights, centroids, chunk):
         onehot = jax.nn.one_hot(codes, L, dtype=jnp.float32) * wb[:, None]
         # deviation accumulation: exact-cover clusters contribute 0
         delta = xb - centroids[codes]
-        return (dsums + onehot.T @ delta,
+        return (dsums + jnp.matmul(onehot.T, delta, precision=HIGHEST),
                 counts + onehot.sum(axis=0)), None
 
     (dsums, counts), _ = jax.lax.scan(
@@ -183,8 +186,7 @@ def _update_scan(assign, x, weights, centroids, chunk):
 def _update_pallas(x: jax.Array, weights: jax.Array, centroids: jax.Array,
                    chunk: int):
     from repro.kernels import ops
-    return ops.lloyd_update(x, centroids, weights,
-                            block_n=min(512, max(chunk, 8)))
+    return ops.lloyd_update(x, centroids, weights)
 
 
 _REGISTRY: Dict[str, Backend] = {

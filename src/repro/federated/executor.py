@@ -130,6 +130,11 @@ class CohortExecutor:
         with ``cut_state`` in client-major layout."""
         raise NotImplementedError
 
+    def lower(self, state: TrainState, parts: Sequence[Dict]):
+        """The synchronous step ``execute(state, parts)`` would run, lowered
+        for its arguments (``.compile()`` it to inspect the program)."""
+        raise NotImplementedError
+
     # ---- measurement routing ----------------------------------------------
     def client_forward(self, client_params, batch):
         """One client's cut activations for the wire measurement."""
@@ -149,11 +154,11 @@ class StackedExecutor(CohortExecutor):
         self._claim(trainer)
         step_key = jax.random.PRNGKey(trainer.seed) \
             if trainer.stochastic_downlink else None
-        # round() is public API whose callers may reuse the input state:
-        # the fused step must not donate; the weighted step is only called
-        # inside run()'s execute, which rebinds the state — donate it
+        # both steps donate the input state (round() and run() rebind it):
+        # without donation the old and new params + optimizer state are live
+        # at once, which a published-width model does not fit on one chip
         self._step = make_train_step(trainer.model, trainer.optimizer,
-                                     quantize=trainer.quantize, donate=False,
+                                     quantize=trainer.quantize,
                                      step_key=step_key)
         self._weighted_step = make_weighted_step(
             trainer.model, trainer.optimizer, quantize=trainer.quantize,
@@ -161,6 +166,9 @@ class StackedExecutor(CohortExecutor):
 
     def per_client_layout(self, is_async: bool) -> bool:
         return is_async
+
+    def lower(self, state, parts):
+        return self._step.lower(state, self.trainer.stack_batches(parts))
 
     def execute(self, state, parts, weights=None, cut_state=None):
         # the span measures host dispatch time (the step is async on
@@ -255,10 +263,6 @@ class MeshExecutor(CohortExecutor):
             self._steps[scope] = make_mesh_step(
                 self.trainer.model, self.trainer.optimizer, self.mesh,
                 quantize=self.trainer.quantize,
-                # mirror the stacked split: the synchronous step backs the
-                # public round() (callers may reuse the input state), the
-                # weighted step only ever runs inside run()'s execute
-                donate=scope == "client",
                 step_key=self._step_key, correction_scope=scope)
         return self._steps[scope]
 
@@ -272,32 +276,43 @@ class MeshExecutor(CohortExecutor):
             lambda x: jnp.concatenate(
                 [x, jnp.repeat(x[-1:], pad, axis=0)], axis=0), tree)
 
+    def _placed_args(self, state, parts, weights, cut_state):
+        """The mesh step's arguments, padded to the slot count and placed:
+        client-major arrays sharded over ``clients``, the state
+        replicated."""
+        n = len(parts)
+        pad = self._slot_count(n) - n
+        w = jnp.asarray(list(weights) if weights is not None else [1.0] * n,
+                        jnp.float32)
+        w = jnp.concatenate([w, jnp.ones((pad,), jnp.float32)]) \
+            if pad else w
+        mask = jnp.concatenate([jnp.ones((n,), jnp.float32),
+                                jnp.zeros((pad,), jnp.float32)]) \
+            if pad else jnp.ones((n,), jnp.float32)
+        sh_clients = clients_sharding(self.mesh)
+        batches = jax.device_put(self._pad(_stack_parts(parts), pad),
+                                 sh_clients)
+        w = jax.device_put(w, sh_clients)
+        mask = jax.device_put(mask, sh_clients)
+        if cut_state is not None:
+            cut_state = jax.device_put(self._pad(cut_state, pad), sh_clients)
+        state = jax.device_put(state, replicated_sharding(self.mesh))
+        return state, batches, w, mask, cut_state
+
+    def lower(self, state, parts):
+        return self._get_step("cohort").lower(
+            *self._placed_args(state, parts, None, None))
+
     def execute(self, state, parts, weights=None, cut_state=None):
         sync = weights is None
         n = len(parts)
         slots = self._slot_count(n)
-        pad = slots - n
         with obs.span("executor.execute", cat="executor", backend=self.name,
                       clients=n, slots=slots, shards=self.num_shards,
                       mode="sync" if sync else "weighted"):
-            w = jnp.asarray(list(weights) if not sync else [1.0] * n,
-                            jnp.float32)
-            w = jnp.concatenate([w, jnp.ones((pad,), jnp.float32)]) \
-                if pad else w
-            mask = jnp.concatenate([jnp.ones((n,), jnp.float32),
-                                    jnp.zeros((pad,), jnp.float32)]) \
-                if pad else jnp.ones((n,), jnp.float32)
-            sh_clients = clients_sharding(self.mesh)
-            batches = jax.device_put(self._pad(_stack_parts(parts), pad),
-                                     sh_clients)
-            w = jax.device_put(w, sh_clients)
-            mask = jax.device_put(mask, sh_clients)
-            if cut_state is not None:
-                cut_state = jax.device_put(self._pad(cut_state, pad),
-                                           sh_clients)
-            state = jax.device_put(state, replicated_sharding(self.mesh))
             step = self._get_step("cohort" if sync else "client")
-            state, metrics = step(state, batches, w, mask, cut_state)
+            state, metrics = step(*self._placed_args(state, parts, weights,
+                                                     cut_state))
             if sync:
                 # keep synchronous metrics key-compatible with the stacked
                 # path

@@ -363,7 +363,10 @@ class FederatedTrainer:
     def round(self, state: TrainState, key: jax.Array):
         """One synchronous server update on a fresh cohort, through the
         configured executor (the stacked default concatenates the cohort
-        into one fused batch — the bitwise-historical path)."""
+        into one fused batch — the bitwise-historical path).
+
+        The step donates ``state``: rebind it to the returned state and do
+        not read the one passed in (copy it first to keep it)."""
         ids = sample_clients(self._rng, self.data.num_clients, self.cohort)
         parts = [self.client_batch_for(cid, key) for cid in ids]
         return self.executor.execute(state, parts)
@@ -690,7 +693,7 @@ class FederatedTrainer:
         autoscaler uses to re-run segments of one training run under
         successive (cohort, policy, compressor) plans
         (``federated/autoscale.py``). The caller's state is copied on
-        entry: the executors' weighted steps donate their input buffers,
+        entry: the executors' steps donate their input buffers,
         and donation must never reach arrays the caller still owns.
 
         ``cursor`` / ``on_round`` are the crash-recovery hooks forwarded

@@ -2,8 +2,8 @@
 
 The Lloyd iteration is the per-step K-means tax FedLite pays at the cut
 layer: for every train step, every iteration re-reads the activations,
-assigns them, and accumulates centroid statistics. The PR 1 jnp path fuses
-the assign into the scan body, but XLA still materializes a ``(chunk, L)``
+assigns them, and accumulates centroid statistics. The jnp path fuses the
+assign into the scan body, but XLA still materializes a ``(chunk, L)``
 one-hot and issues a second centroid read (the ``cents[codes]`` gather) per
 scan step. This kernel does the whole iteration in ONE HBM sweep over X:
 
@@ -11,24 +11,24 @@ scan step. This kernel does the whole iteration in ONE HBM sweep over X:
     dsums[l]  += Σ_{i: codes_i=l} w_i · (x_i − c_l)         (MXU matmul)
     counts[l] += Σ_{i: codes_i=l} w_i
 
-The one-hot exists only in VREGs/VMEM; the codebook is VMEM-resident for
-the whole grid; the accumulators are a single (L, D) + (1, L) output block
-revisited by every grid step (TPU grids are sequential, so the constant
-``index_map`` makes the output an accumulator — zeroed at ``program_id 0``).
-HBM traffic per iteration: one read of X (+ the (N,) weights) and O(L·D)
-accumulator writes, vs the scan's X read + one-hot materialization + second
-centroid read.
+It works on the transposed activations Xᵀ (D, N) and (1, N) weights — the
+lane-dense layout ``kmeans_assign.py`` describes. The one-hot exists only
+in VREGs/VMEM; the codebook is VMEM-resident for the whole grid; the
+accumulators are a single (L, D) + (L, 1) output block revisited by every
+grid step (TPU grids are sequential, so the constant ``index_map`` makes
+the output an accumulator — zeroed at ``program_id 0``). HBM traffic per
+iteration: one read of X (+ the weights) and O(L·D) accumulator writes.
 
 Numerics: statistics are accumulated as *deviations from the current
-centroid* (``x − c_old``), matching the jnp scan bit-for-bit in structure —
-a cluster whose members all equal its centroid contributes an exactly-zero
-update (products of exact one-hot rows with an exactly-zero delta), which
-the FedLite ≡ SplitFed gradient-equivalence test depends on. Rows with
-weight 0 (padding) contribute exactly nothing. Empty clusters report
-``counts == 0`` and the caller keeps the previous centroid.
+centroid* (``x − c_old``), matching the jnp scan in structure — a cluster
+whose members all equal its centroid contributes an exactly-zero update
+(the one-hot gather runs at HIGHEST precision, so it is exact), which the
+FedLite ≡ SplitFed gradient-equivalence test depends on. Rows with weight 0
+(padding) contribute exactly nothing. Empty clusters report ``counts == 0``
+and the caller keeps the previous centroid.
 
-Validated against ``ref.lloyd_update_ref`` in interpret mode (CPU
-container); compiled Mosaic on TPU.
+Validated against ``ref.lloyd_update_ref`` in interpret mode on CPU;
+compiled by Mosaic on TPU.
 """
 
 from __future__ import annotations
@@ -39,10 +39,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-NEG = -1e30
+from repro.kernels.kmeans_assign import (HIGHEST, codebook_operands,
+                                         scores_and_codes)
+from repro.kernels.pq_quantize import onehot_gather
 
 
-def _update_kernel(x_ref, w_ref, c_ref, cnorm_ref, lmask_ref,
+def _update_kernel(xt_ref, w_ref, c_ref, ct_ref, cnorm_ref, lmask_ref,
                    dsums_ref, counts_ref):
     # zero the accumulators once; later grid steps revisit the same block
     @pl.when(pl.program_id(0) == 0)
@@ -50,59 +52,51 @@ def _update_kernel(x_ref, w_ref, c_ref, cnorm_ref, lmask_ref,
         dsums_ref[...] = jnp.zeros_like(dsums_ref)
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    x = x_ref[...].astype(jnp.float32)              # (BN, D)
-    w = w_ref[...].astype(jnp.float32)              # (BN,)
+    xt = xt_ref[...].astype(jnp.float32)            # (D, BN)
+    w = w_ref[...].astype(jnp.float32)              # (1, BN)
     c = c_ref[...].astype(jnp.float32)              # (L, D)
-    # scores[i,l] = 2·x_i·c_l − ‖c_l‖²   (MXU; ‖x‖² is constant over l)
-    scores = 2.0 * jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) - cnorm_ref[...]
-    scores = jnp.where(lmask_ref[...] > 0, scores, NEG)
-    codes = jnp.argmax(scores, axis=-1)
-    # one-hot lives only in VREGs; the gather is a one-hot matmul (MXU)
-    onehot = (codes[:, None] == jnp.arange(c.shape[0])[None, :]
-              ).astype(jnp.float32)
-    zt = jax.lax.dot_general(onehot, c, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    delta = x - zt                                  # exact 0 on exact cover
-    ohw = onehot * w[:, None]                       # padded rows weigh 0
+    _, _, codes = scores_and_codes(xt, c, cnorm_ref[...], lmask_ref[...])
+    onehot, zt = onehot_gather(codes, ct_ref[...].astype(jnp.float32),
+                               c.shape[0])
+    delta = xt - zt                                 # exact 0 on exact cover
+    ohw = onehot * w                                # padded rows weigh 0
     dsums_ref[...] += jax.lax.dot_general(
-        ohw, delta, (((0,), (0,)), ((), ())),
+        ohw, delta, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)
-    counts_ref[...] += jnp.sum(ohw, axis=0)[None, :]
+    counts_ref[...] += jnp.sum(ohw, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def lloyd_update_kernel(x: jax.Array, weights: jax.Array,
+def lloyd_update_kernel(xt: jax.Array, weights: jax.Array,
                         centroids: jax.Array, lmask: jax.Array, *,
-                        block_n: int = 512, interpret: bool = False):
-    """x: (N, D) with N % block_n == 0; weights: (N,); centroids: (L, D);
+                        block_n: int, interpret: bool = False):
+    """xt: (D, N) with N % block_n == 0; weights: (1, N); centroids: (L, D);
     lmask: (L,) 1.0 = valid centroid.
 
     Returns (dsums (L, D) f32 = Σ onehot·(x − c_old), counts (L,) f32).
     """
-    n, d = x.shape
+    d, n = xt.shape
     l = centroids.shape[0]
-    cnorm = jnp.sum(centroids.astype(jnp.float32) ** 2, axis=-1)[None, :]
+    cnorm, lm = codebook_operands(centroids, lmask)
     dsums, counts = pl.pallas_call(
         _update_kernel,
         grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),   # stream X tiles
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((d, block_n), lambda i: (0, i)),   # stream Xᵀ tiles
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((l, d), lambda i: (0, 0)),         # codebook resident
-            pl.BlockSpec((1, l), lambda i: (0, 0)),
-            pl.BlockSpec((1, l), lambda i: (0, 0)),
+            pl.BlockSpec((d, l), lambda i: (0, 0)),
+            pl.BlockSpec((l, 1), lambda i: (0, 0)),
+            pl.BlockSpec((l, 1), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((l, d), lambda i: (0, 0)),         # accumulators:
-            pl.BlockSpec((1, l), lambda i: (0, 0)),         # same block ∀ i
+            pl.BlockSpec((l, 1), lambda i: (0, 0)),         # same block ∀ i
         ],
         out_shape=[
             jax.ShapeDtypeStruct((l, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, l), jnp.float32),
+            jax.ShapeDtypeStruct((l, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, weights.astype(jnp.float32), centroids, cnorm,
-      lmask[None, :].astype(jnp.float32))
-    return dsums, counts[0]
+    )(xt, weights.astype(jnp.float32), centroids, centroids.T, cnorm, lm)
+    return dsums, counts[:, 0]

@@ -1,9 +1,14 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles padding (N to a block multiple, L to a lane-friendly multiple) and
-interpret-mode selection: ``interpret=True`` on non-TPU backends so the CPU
-container executes the kernel bodies in Python for validation, compiled
-Mosaic kernels on real TPUs.
+Handles layout and padding, and interpret-mode selection: ``interpret=True``
+on non-TPU backends so the CPU container executes the kernel bodies in
+Python for validation, compiled Mosaic kernels whenever the default backend
+is a TPU (there is no path that interprets on the chip).
+
+Every block a wrapper hands a kernel is legal for Mosaic: the PQ kernels
+take the points on lanes (``x.T``, see ``kmeans_assign.py``) in blocks of a
+multiple of 128 lanes; the scalar-quantize kernel takes (8k, 128m) tiles or
+the whole (padded) dimension. The public signatures stay row-major (N, D).
 """
 
 from __future__ import annotations
@@ -16,63 +21,93 @@ import jax.numpy as jnp
 from repro.kernels.kmeans_assign import kmeans_assign_kernel
 from repro.kernels.pq_quantize import pq_quantize_kernel
 
+LANE = 128
+SUBLANE = 8
+# cap on the (L, block_n) f32 score tile a PQ kernel holds in VMEM (2 MiB)
+SCORE_TILE_ELEMS = 1 << 19
+# scalar-quantize column tile: three (256, 1024) f32/int32 blocks, double-
+# buffered, take 6 MiB of scoped VMEM whatever the row width
+SCALARQ_COL_BLOCK = 1024
+
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _pad_axis(x, block: int, axis: int):
+    """Zero-pad ``axis`` up to a multiple of ``block``."""
+    pad = (-x.shape[axis]) % block
+    if pad:
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, pad)
+        x = jnp.pad(x, widths)
+    return x
+
+
 def _pad_rows(x, block):
-    n = x.shape[0]
-    pad = (-n) % block
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
-    return x, n
+    return _pad_axis(x, block, 0), x.shape[0]
 
 
-def _pad_centroids(c, lane: int = 8):
+def _pad_centroids(c):
+    """Pad L to a sublane multiple; returns (padded codebook, (L_pad,) mask
+    with 1.0 on the real centroids)."""
     l = c.shape[0]
-    pad = (-l) % lane
-    lmask = jnp.concatenate([jnp.ones(l, jnp.float32),
-                             jnp.zeros(pad, jnp.float32)])
-    if pad:
-        c = jnp.concatenate([c, jnp.zeros((pad, c.shape[1]), c.dtype)])
-    return c, lmask
+    lmask = (jnp.arange(_round_up(l, SUBLANE)) < l).astype(jnp.float32)
+    return _pad_axis(c, SUBLANE, 0), lmask
+
+
+def _lane_block(n: int, num_clusters: int, block_n: int) -> int:
+    """Points per grid step for the PQ kernels: a multiple of 128 lanes, no
+    larger than ``block_n``, the score-tile cap, or N itself (rounded up)."""
+    cap = max(LANE, SCORE_TILE_ELEMS // _round_up(num_clusters, SUBLANE))
+    b = max(LANE, min(block_n, cap) // LANE * LANE)
+    return min(b, _round_up(n, LANE))
+
+
+def _points_on_lanes(x, block: int):
+    """(N, D) -> zero-padded (D, N_pad), N_pad a multiple of ``block``."""
+    return _pad_axis(x.T, block, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def kmeans_assign(x: jax.Array, centroids: jax.Array, *,
-                  block_n: int = 512, interpret: bool | None = None):
+                  block_n: int = 2048, interpret: bool | None = None):
     """codes[i] = argmin_l ‖x_i − c_l‖²; also returns squared distances.
 
     x: (N, D) any float dtype; centroids: (L, D). Arbitrary N, L (padded
     internally).
     """
     interpret = _interpret_default() if interpret is None else interpret
-    block_n = min(block_n, max(8, x.shape[0]))
-    xp, n = _pad_rows(x, block_n)
+    n = x.shape[0]
+    block = _lane_block(n, centroids.shape[0], block_n)
     cp, lmask = _pad_centroids(centroids)
-    codes, dist = kmeans_assign_kernel(xp, cp, lmask, block_n=block_n,
-                                       interpret=interpret)
-    return codes[:n], dist[:n]
+    codes, dist = kmeans_assign_kernel(_points_on_lanes(x, block), cp, lmask,
+                                       block_n=block, interpret=interpret)
+    return codes[0, :n], dist[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def pq_quantize(x: jax.Array, centroids: jax.Array, *,
-                block_n: int = 512, interpret: bool | None = None):
+                block_n: int = 2048, interpret: bool | None = None):
     """Fused assign + dequantize + residual. Returns (z̃, residual, codes)."""
     interpret = _interpret_default() if interpret is None else interpret
-    block_n = min(block_n, max(8, x.shape[0]))
-    xp, n = _pad_rows(x, block_n)
+    n = x.shape[0]
+    block = _lane_block(n, centroids.shape[0], block_n)
     cp, lmask = _pad_centroids(centroids)
-    zt, resid, codes = pq_quantize_kernel(xp, cp, lmask, block_n=block_n,
+    zt, resid, codes = pq_quantize_kernel(_points_on_lanes(x, block), cp,
+                                          lmask, block_n=block,
                                           interpret=interpret)
-    return zt[:n], resid[:n], codes[:n]
+    return zt[:, :n].T, resid[:, :n].T, codes[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def lloyd_update(x: jax.Array, centroids: jax.Array,
                  weights: jax.Array | None = None, *,
-                 block_n: int = 512, interpret: bool | None = None):
+                 block_n: int = 2048, interpret: bool | None = None):
     """Fused Lloyd-iteration statistics: assign + deviation-accumulate in one
     HBM sweep (``kernels/lloyd_update.py``).
 
@@ -83,33 +118,41 @@ def lloyd_update(x: jax.Array, centroids: jax.Array,
     """
     from repro.kernels.lloyd_update import lloyd_update_kernel
     interpret = _interpret_default() if interpret is None else interpret
+    n = x.shape[0]
     l = centroids.shape[0]
     if weights is None:
-        weights = jnp.ones((x.shape[0],), jnp.float32)
-    block_n = min(block_n, max(8, x.shape[0]))
-    xp, n = _pad_rows(x, block_n)
-    wp, _ = _pad_rows(weights.astype(jnp.float32), block_n)
+        weights = jnp.ones((n,), jnp.float32)
+    block = _lane_block(n, l, block_n)
+    wp = _pad_axis(weights.astype(jnp.float32)[None, :], block, 1)
     cp, lmask = _pad_centroids(centroids)
-    dsums, counts = lloyd_update_kernel(xp, wp, cp, lmask, block_n=block_n,
+    dsums, counts = lloyd_update_kernel(_points_on_lanes(x, block), wp, cp,
+                                        lmask, block_n=block,
                                         interpret=interpret)
     return dsums[:l], counts[:l]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_n", "interpret"))
 def scalar_quantize(x: jax.Array, lo: jax.Array, scale: jax.Array,
-                    bits: int, *, block_n: int = 512,
+                    bits: int, *, block_n: int = 256,
                     interpret: bool | None = None):
     """Fused uniform b-bit quantize + dequantize (scalarq compressor hot
     loop). x: (N, D) any float dtype; lo/scale: () tensor-wide range.
-    Returns (codes (N, D) int32, recon (N, D) f32)."""
+    Returns (codes (N, D) int32, recon (N, D) f32).
+
+    Blocks are (block_n, SCALARQ_COL_BLOCK) tiles — sublane/lane
+    multiples, or the whole padded dimension when it is smaller — so VMEM
+    use is bounded by the tile, not by the row width."""
     from repro.kernels.scalar_quant import scalar_quantize_kernel
     interpret = _interpret_default() if interpret is None else interpret
-    block_n = min(block_n, max(8, x.shape[0]))
-    xp, n = _pad_rows(x, block_n)
+    n, d = x.shape
+    bn = min(max(SUBLANE, block_n // SUBLANE * SUBLANE),
+             _round_up(n, SUBLANE))
+    bd = min(d, SCALARQ_COL_BLOCK)
+    xp = _pad_axis(_pad_axis(x, bn, 0), bd, 1)
     codes, recon = scalar_quantize_kernel(xp, lo, scale, bits=bits,
-                                          block_n=block_n,
+                                          block_n=bn, block_d=bd,
                                           interpret=interpret)
-    return codes[:n], recon[:n]
+    return codes[:n, :d], recon[:n, :d]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_n", "interpret"))

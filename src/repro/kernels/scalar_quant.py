@@ -28,7 +28,7 @@ from jax.experimental import pallas as pl
 
 
 def _quantize_kernel(levels, x_ref, lo_ref, scale_ref, codes_ref, recon_ref):
-    x = x_ref[...].astype(jnp.float32)              # (BN, D)
+    x = x_ref[...].astype(jnp.float32)              # (BN, BD)
     lo = lo_ref[0, 0]
     scale = scale_ref[0, 0]
     codes = jnp.clip(jnp.round((x - lo) / scale), 0, levels)
@@ -36,11 +36,17 @@ def _quantize_kernel(levels, x_ref, lo_ref, scale_ref, codes_ref, recon_ref):
     recon_ref[...] = (lo + codes * scale).astype(recon_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits", "block_n", "block_d",
+                                             "interpret"))
 def scalar_quantize_kernel(x: jax.Array, lo: jax.Array, scale: jax.Array,
-                           *, bits: int, block_n: int = 512,
+                           *, bits: int, block_n: int, block_d: int,
                            interpret: bool = False):
-    """x: (N, D), N % block_n == 0; lo/scale: () f32 tensor-wide range.
+    """x: (N, D), N % block_n == 0 and D % block_d == 0; lo/scale: () f32
+    tensor-wide range.
+
+    The grid tiles rows AND columns: three (block_n, block_d) blocks (x,
+    codes, recon), each double-buffered, must fit scoped VMEM whatever the
+    row width — a whole-row block of a (200, 9216) cut does not.
 
     Returns (codes (N, D) int32 in [0, 2^bits), recon (N, D) f32).
     """
@@ -48,15 +54,15 @@ def scalar_quantize_kernel(x: jax.Array, lo: jax.Array, scale: jax.Array,
     levels = (1 << bits) - 1
     codes, recon = pl.pallas_call(
         functools.partial(_quantize_kernel, float(levels)),
-        grid=(n // block_n,),
+        grid=(n // block_n, d // block_d),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
+            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, d), jnp.int32),

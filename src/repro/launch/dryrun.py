@@ -12,9 +12,11 @@ Run:  PYTHONPATH=src python -m repro.launch.dryrun --arch llama3_8b \
       PYTHONPATH=src python -m repro.launch.dryrun --all
 """
 
-# The host platform must expose 512 fake devices BEFORE jax initializes —
-# these two lines must stay the first statements in this module.
+# The host platform must expose 512 fake devices BEFORE jax initializes,
+# and the dry run must never take an attached chip: it lowers for described
+# pods on host devices. These statements must stay first in this module.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
@@ -31,13 +33,14 @@ from repro.configs.base import (ARCH_IDS, INPUT_SHAPES, get_arch,
                                 supports_shape)
 from repro.core.fedlite import make_train_step
 from repro.launch import analysis
-from repro.launch.mesh import (HBM_BYTES, HBM_BW, ICI_BW_PER_LINK,
-                               PEAK_FLOPS_BF16, make_production_mesh)
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND, device_peaks,
+                               make_production_mesh)
 from repro.launch.specs import (cache_specs, decode_token_specs, input_specs,
                                 make_model, state_specs)
 from repro.optim import get_optimizer
 from repro.sharding import use_mesh
 
+PEAKS = device_peaks(PRODUCTION_DEVICE_KIND)
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
@@ -99,7 +102,8 @@ def lower_combo(arch_id: str, shape_id: str, mesh, *, with_pq: bool = True,
     wire = analysis.total_wire_bytes(coll)
     roof = analysis.roofline_terms(
         cost.get("flops", 0.0), cost.get("bytes_accessed", 0.0), wire,
-        peak_flops=PEAK_FLOPS_BF16, hbm_bw=HBM_BW, ici_bw=ICI_BW_PER_LINK)
+        peak_flops=PEAKS.flops_bf16, hbm_bw=PEAKS.hbm_bw,
+        ici_bw=PEAKS.ici_bw_per_link)
 
     # MODEL_FLOPS: 6·N_active·tokens (train fwd+bwd) or 2·N_active·tokens
     n_active = cfg.param_count(active_only=True)
@@ -125,7 +129,7 @@ def lower_combo(arch_id: str, shape_id: str, mesh, *, with_pq: bool = True,
         "cost": cost, "memory": mem, "collectives": coll,
         "wire_bytes_per_device": wire,
         "device_bytes": device_bytes,
-        "fits_16GiB": device_bytes <= HBM_BYTES,
+        "fits_16GiB": device_bytes <= PEAKS.hbm_bytes,
         "model_flops_per_device": model_flops_per_device,
         "useful_flops_fraction": (model_flops_per_device /
                                   max(cost.get("flops", 1.0), 1.0)),
@@ -177,7 +181,7 @@ def run_one(arch_id, shape_id, mesh_kind, out_dir, *, with_pq=True,
                 rec["tpu_bf16_estimate"] = {
                     "f32_temp_bytes": rec32["memory"]["temp_size_in_bytes"],
                     "device_bytes_estimate": dev_est,
-                    "fits_16GiB_estimate": dev_est <= HBM_BYTES,
+                    "fits_16GiB_estimate": dev_est <= PEAKS.hbm_bytes,
                 }
             except Exception as e:  # noqa: BLE001
                 rec["tpu_bf16_estimate"] = {"error": str(e)[:200]}

@@ -8,11 +8,12 @@ builds a mesh (the dry-run sets XLA_FLAGS for 512 host devices first).
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
-from repro.sharding.ctx import CLIENTS_AXIS, AxisType, make_mesh
+from repro.sharding.ctx import CLIENTS_AXIS
 
 SINGLE_POD = (16, 16)                  # 256 chips / pod
 MULTI_POD = (2, 16, 16)                # 2 pods = 512 chips
@@ -33,8 +34,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (launch/dryrun.py does this)")
-    return make_mesh(shape, axes, devices=devices[:n],
-                     axis_types=(AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pods: int = 0):
@@ -45,8 +46,8 @@ def make_debug_mesh(data: int = 2, model: int = 2, pods: int = 0):
     else:
         shape, axes = (data, model), ("data", "model")
     n = math.prod(shape)
-    return make_mesh(shape, axes, devices=jax.devices()[:n],
-                     axis_types=(AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_clients_mesh(shards: int = 0):
@@ -67,12 +68,34 @@ def make_clients_mesh(shards: int = 0):
             f"need {n} devices for a {n}-shard clients mesh, have "
             f"{len(devices)} — set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={n} before importing jax")
-    return make_mesh((n,), (CLIENTS_AXIS,), devices=devices[:n],
-                     axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), (CLIENTS_AXIS,), devices=devices[:n],
+                         axis_types=(AxisType.Auto,))
 
 
-# TPU v5e hardware constants for the roofline model (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW_PER_LINK = 50e9          # bytes/s per link (~45-100 GB/s depending on gen)
-HBM_BYTES = 16 * 1024 ** 3      # 16 GiB
+class DevicePeaks(NamedTuple):
+    """Published per-chip peaks for the roofline model."""
+    flops_bf16: float       # FLOP/s
+    hbm_bw: float           # bytes/s
+    ici_bw_per_link: float  # bytes/s per link
+    hbm_bytes: int
+
+
+# keyed by ``jax.Device.device_kind``. TPU v5e: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per
+# chip over 4 links = 50 GB/s per link)
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bw=819e9,
+                               ici_bw_per_link=50e9, hbm_bytes=16 * 1024 ** 3),
+}
+# the chip the production meshes above are built for
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    """Peaks of the device ``kind``; an unknown kind is an error, never a
+    silent default."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         f"known: {sorted(DEVICE_PEAKS)}") from None

@@ -21,6 +21,7 @@ from repro.checkpointing import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs.base import ARCH_IDS, get_arch
 from repro.core.fedlite import TrainState, comm_report, make_train_step
 from repro.data.synthetic import make_lm_batch
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import default_pq, make_model
 from repro.optim import get_optimizer, warmup_cosine
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, smoke=args.smoke)
     mesh = None if args.mesh == "none" else make_production_mesh(

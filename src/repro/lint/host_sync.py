@@ -33,8 +33,8 @@ from repro.lint.core import (Finding, LintContext, LintPass, Module,
                              call_name, dotted_name, keyword_arg)
 
 _JIT_NAMES = {"jit", "jax.jit"}
-_TRACE_WRAPPERS = {"shard_map", "jax.experimental.shard_map.shard_map",
-                   "pmap", "jax.pmap", "vmap", "jax.vmap"}
+_TRACE_WRAPPERS = {"shard_map", "jax.shard_map",
+                   "jax.experimental.shard_map.shard_map", "pmap", "jax.pmap", "vmap", "jax.vmap"}
 _NP_HOST = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",
             "onp.asarray", "onp.array"}
 _SYNC_ATTRS = {"item", "block_until_ready"}

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 _CALLBACK_PRIMITIVES = {"pure_callback", "io_callback", "debug_callback",
-                        "outside_call"}
+                        "debug_print", "outside_call"}
 
 
 def iter_eqns(jaxpr) -> Iterable:

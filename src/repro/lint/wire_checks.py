@@ -69,8 +69,10 @@ def _strip_docstring(fn: ast.FunctionDef) -> ast.FunctionDef:
 
 
 def _encoder_hash(fn: ast.FunctionDef) -> str:
-    dump = ast.dump(_strip_docstring(fn), annotate_fields=False)
-    return hashlib.sha256(dump.encode()).hexdigest()[:16]
+    # hash the normalized source (``ast.unparse``), not ``ast.dump``: the
+    # dump's node fields change between Python versions, the source does not
+    src = ast.unparse(_strip_docstring(fn))
+    return hashlib.sha256(src.encode()).hexdigest()[:16]
 
 
 def _packed_version(fn: ast.FunctionDef,

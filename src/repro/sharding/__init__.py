@@ -1,10 +1,11 @@
+from jax import make_mesh
+from jax.sharding import AxisType
+
 from repro.sharding.ctx import (
     CLIENTS_AXIS,
-    AxisType,
     axis_size,
     clients_sharding,
     current_mesh,
-    make_mesh,
     replicated_sharding,
     set_mesh,
     shard,

@@ -14,8 +14,6 @@ single-pod ``("data", "model")`` mesh and the multi-pod
 from __future__ import annotations
 
 import contextlib
-import enum
-import inspect
 from typing import Optional, Sequence, Union
 
 import jax
@@ -44,47 +42,6 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
     """Fully-replicated placement on ``mesh`` (the train state's layout
     under the cohort-parallel executor)."""
     return NamedSharding(mesh, P())
-
-
-# ---------------------------------------------------------------------------
-# jax.sharding.AxisType compat shim
-#
-# AxisType (and make_mesh's axis_types kwarg) only exist in newer JAX; the
-# pinned 0.4.x raises AttributeError. All axis-type usage in this repo is
-# AxisType.Auto — the 0.4.x default behavior — so on old JAX the enum below
-# stands in and make_mesh() silently drops the kwarg.
-# ---------------------------------------------------------------------------
-
-try:
-    AxisType = jax.sharding.AxisType
-except AttributeError:
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              devices=None, axis_types=None) -> Mesh:
-    """``jax.make_mesh`` accepting ``axis_types`` on every supported JAX.
-
-    On JAX versions whose ``make_mesh`` lacks the kwarg, non-Auto axis types
-    are unrepresentable — reject them rather than silently mis-shard.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None:
-        if _MAKE_MESH_HAS_AXIS_TYPES:
-            kwargs["axis_types"] = axis_types
-        elif any(t is not AxisType.Auto for t in axis_types):
-            raise ValueError(
-                f"axis_types={axis_types} need jax.make_mesh support for "
-                "axis_types (this JAX only provides Auto semantics)")
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

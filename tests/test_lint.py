@@ -366,7 +366,10 @@ def test_host_callback_primitives_detected():
     def g(x):
         jax.debug.print("x = {x}", x=x)
         return x
-    assert "debug_callback" in jaxprs.host_callback_primitives(g, jnp.ones(3))
+    # jax.debug.print lowers to ``debug_print`` on JAX >= 0.8 (before:
+    # ``debug_callback``); either way it is a host callback
+    found = jaxprs.host_callback_primitives(g, jnp.ones(3))
+    assert {"debug_print", "debug_callback"} & set(found), found
     def h(x):
         return x + 1.0
     assert jaxprs.host_callback_primitives(h, jnp.ones(3)) == []

@@ -1,0 +1,9 @@
+"""Share of the traced window, in %, in which the device is idle while
+the host assembles the round: idle time inside the program's
+``trainer.round`` spans and outside its ``executor.dispatch`` spans,
+averaged over the chips."""
+
+
+def read(ctx):
+    from bench import program_trace
+    return program_trace.idle_share(ctx, __file__, 0)

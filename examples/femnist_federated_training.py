@@ -39,7 +39,7 @@ move (cohort, policy, downlink codec) between segments:
 
 Telemetry: ``--emit-trace [PATH]`` records the run through the
 `repro.obs` recorder — scheduler rounds on host AND virtual-clock lanes,
-executor/wire/kmeans spans, the per-round byte ledger — then writes an
+executor and wire spans, the per-round byte ledger — then writes an
 append-only JSONL event log (default ``femnist_trace.jsonl``) plus a
 Perfetto-loadable twin (``--perfetto PATH`` to relocate; load at
 https://ui.perfetto.dev). Summarize with ``python -m repro.obs <jsonl>``:

@@ -623,7 +623,10 @@ def _dl_fwd(z, compressor):
 def _dl_bwd(compressor, _, g):
     if isinstance(compressor, NoneCompressor):
         return (g,)
-    return (compressor.compress(g).recon.astype(g.dtype),)
+    # scoped here, in the backward rule, so the scope lands on the codec's
+    # backward-pass operations
+    with jax.named_scope("fl_downlink_codec"):
+        return (compressor.compress(g).recon.astype(g.dtype),)
 
 
 compress_downlink.defvjp(_dl_fwd, _dl_bwd)
@@ -652,7 +655,8 @@ def _dlk_bwd(compressor, key, g):
     if isinstance(compressor, NoneCompressor):
         gz = g
     else:
-        gz = compressor.compress(g, key=key).recon.astype(g.dtype)
+        with jax.named_scope("fl_downlink_codec"):
+            gz = compressor.compress(g, key=key).recon.astype(g.dtype)
     # integer-dtype primals take float0 cotangents
     return (gz, np.zeros(key.shape, jax.dtypes.float0))
 
@@ -695,8 +699,9 @@ def _dls_bwd(compressor, state, g):
     if isinstance(compressor, NoneCompressor):
         gz = g
     else:
-        comp, _ = compressor.compress_stateful(g, state)
-        gz = comp.recon.astype(g.dtype)
+        with jax.named_scope("fl_downlink_codec"):
+            comp, _ = compressor.compress_stateful(g, state)
+            gz = comp.recon.astype(g.dtype)
     return (gz, _zero_state_cotangent(state))
 
 

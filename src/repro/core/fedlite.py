@@ -31,6 +31,13 @@ from repro.core.split import dtype_bits, tree_bits
 from repro.models.transformer import TransformerLM
 from repro.optim import Optimizer
 
+# The steps' ``jax.named_scope``s (``fl_client`` ... ``fl_optimizer``) live
+# in their ops' metadata, which is what a profiler trace reports. JAX leaves
+# that metadata out of its persistent compilation cache's key by default, so
+# a step compiled by another version of this code, with other scopes or
+# none, would come back from the cache carrying that version's names.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -123,9 +130,10 @@ def make_train_step(model: TransformerLM, optimizer: Optimizer, *,
             loss = loss_sum / microbatches
             metrics = jax.tree.map(lambda m: m[-1], metrics)
 
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = jax.tree.map(operator.add, state.params, updates)
+        with jax.named_scope("fl_optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = jax.tree.map(operator.add, state.params, updates)
         metrics = dict(metrics, loss=loss)
         return TrainState(params, opt_state, state.step + 1), metrics
 
@@ -186,9 +194,10 @@ def make_weighted_step(model, optimizer: Optimizer, *,
         ghat = jax.tree.map(
             lambda g: jnp.tensordot(w, g.astype(jnp.float32), axes=1)
             .astype(g.dtype), grads)
-        updates, opt_state = optimizer.update(ghat, state.opt_state,
-                                              state.params)
-        params = jax.tree.map(operator.add, state.params, updates)
+        with jax.named_scope("fl_optimizer"):
+            updates, opt_state = optimizer.update(ghat, state.opt_state,
+                                                  state.params)
+            params = jax.tree.map(operator.add, state.params, updates)
         # the cut state is carry, not a scalar metric: keep its client axis
         new_cut = metrics.pop("cut_state", None)
         metrics = jax.tree.map(lambda m: jnp.mean(m, axis=0), metrics)
@@ -311,9 +320,10 @@ def make_mesh_step(model, optimizer: Optimizer, mesh, *,
                              cut_state)
         ghat = jax.tree.map(
             lambda g, p: g.astype(p.dtype), gsum, state.params)
-        updates, opt_state = optimizer.update(ghat, state.opt_state,
-                                              state.params)
-        params = jax.tree.map(operator.add, state.params, updates)
+        with jax.named_scope("fl_optimizer"):
+            updates, opt_state = optimizer.update(ghat, state.opt_state,
+                                                  state.params)
+            params = jax.tree.map(operator.add, state.params, updates)
         new_cut = metrics.pop("cut_state", None)
         mf = mask.astype(jnp.float32)
         metrics = jax.tree.map(lambda x: jnp.sum(x * mf) / cnt, metrics)

@@ -62,7 +62,6 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import obs
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -293,39 +292,33 @@ def lloyd(x: jax.Array, num_clusters: int, num_iters: int = 8, *,
     L = num_clusters
     b = get_backend(backend)
 
-    # records eager calls only (a no-op while jit-tracing; shapes are
-    # static either way, so the args never capture tracers)
-    with obs.span("kmeans.lloyd", cat="kmeans", n=int(n), d=int(d),
-                  clusters=int(L), iters=int(num_iters), backend=b.name,
-                  warm=init_centroids is not None):
-        # pad N up to a multiple of chunk; padded rows carry zero weight
-        xc, n, n_pad = _pad_chunks(x, chunk)
-        weights = jnp.concatenate(
-            [jnp.ones((n,), jnp.float32), jnp.zeros((n_pad,), jnp.float32)])
-        x_flat = xc.reshape(-1, d)
-        chunk_eff = xc.shape[1]
+    # pad N up to a multiple of chunk; padded rows carry zero weight
+    xc, n, n_pad = _pad_chunks(x, chunk)
+    weights = jnp.concatenate(
+        [jnp.ones((n,), jnp.float32), jnp.zeros((n_pad,), jnp.float32)])
+    x_flat = xc.reshape(-1, d)
+    chunk_eff = xc.shape[1]
 
-        if init_centroids is not None:
-            cents0 = init_centroids.astype(jnp.float32)
-            if cents0.shape != (L, d):
-                raise ValueError(
-                    f"init_centroids {cents0.shape} != ({L}, {d})")
+    if init_centroids is not None:
+        cents0 = init_centroids.astype(jnp.float32)
+        if cents0.shape != (L, d):
+            raise ValueError(
+                f"init_centroids {cents0.shape} != ({L}, {d})")
+    else:
+        cents0 = _init_centroids(x, L, key)
+
+    def lloyd_iter(_, cents):
+        if b.update is not None:
+            dsums, counts = b.update(x_flat, weights, cents, chunk_eff)
         else:
-            cents0 = _init_centroids(x, L, key)
+            dsums, counts = _update_scan(b.assign, x_flat, weights,
+                                         cents, chunk_eff)
+        # empty clusters keep their previous centroid
+        return cents + jnp.where(counts[:, None] > 0,
+                                 dsums / jnp.maximum(counts[:, None], 1.0),
+                                 0.0)
 
-        def lloyd_iter(_, cents):
-            if b.update is not None:
-                dsums, counts = b.update(x_flat, weights, cents, chunk_eff)
-            else:
-                dsums, counts = _update_scan(b.assign, x_flat, weights,
-                                             cents, chunk_eff)
-            # empty clusters keep their previous centroid
-            return cents + jnp.where(counts[:, None] > 0,
-                                     dsums / jnp.maximum(counts[:, None],
-                                                         1.0),
-                                     0.0)
-
-        return jax.lax.fori_loop(0, num_iters, lloyd_iter, cents0)
+    return jax.lax.fori_loop(0, num_iters, lloyd_iter, cents0)
 
 
 def kmeans(x: jax.Array, num_clusters: int, num_iters: int = 8, *,
@@ -348,18 +341,16 @@ def kmeans(x: jax.Array, num_clusters: int, num_iters: int = 8, *,
     in_dtype = x.dtype
     xf = x.astype(jnp.float32)
     n = xf.shape[0]
-    with obs.span("kmeans.kmeans", cat="kmeans", n=int(n),
-                  clusters=int(num_clusters), iters=int(num_iters)):
-        cents = lloyd(xf, num_clusters, num_iters, key=key, chunk=chunk,
-                      backend=backend, init_centroids=init_centroids)
-        b = get_backend(backend)
-        if b.assign_dist is not None:
-            codes, sqdist = b.assign_dist(xf, cents, chunk)
-        else:  # backend without a distance pass: derive from encode
-            _, resid, codes = b.encode(xf, cents, chunk)
-            sqdist = jnp.sum(resid * resid, axis=-1)
-        distortion = jnp.sum(sqdist) / jnp.maximum(n, 1)
-        return KMeansResult(cents.astype(in_dtype), codes, distortion)
+    cents = lloyd(xf, num_clusters, num_iters, key=key, chunk=chunk,
+                  backend=backend, init_centroids=init_centroids)
+    b = get_backend(backend)
+    if b.assign_dist is not None:
+        codes, sqdist = b.assign_dist(xf, cents, chunk)
+    else:  # backend without a distance pass: derive from encode
+        _, resid, codes = b.encode(xf, cents, chunk)
+        sqdist = jnp.sum(resid * resid, axis=-1)
+    distortion = jnp.sum(sqdist) / jnp.maximum(n, 1)
+    return KMeansResult(cents.astype(in_dtype), codes, distortion)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))

@@ -201,12 +201,15 @@ no recorder is configured:
     `Scheduler.run` records every round twice — once on the *host
     wall-clock* lane (what the process spent, jit dispatch only, never a
     device sync) and once on the *scheduler virtual-clock* lane (what
-    the simulated fleet spent) — alongside executor place/execute
-    phases, wire encode/decode, Lloyd/kmeans and checkpoint I/O spans;
-    autoscaler plan moves and straggler cuts are instant events on the
-    same log. Jitted steps return metrics as device arrays through aux
-    pytrees (``obs.counter`` / ``obs.gauge`` / ``obs.histogram`` are
-    jit-safe) into an `obs.MetricsBuffer`, converted with ONE
+    the simulated fleet spent) — alongside ``trainer.round``, executor
+    place/execute/dispatch phases, wire encode/decode and checkpoint I/O
+    spans; autoscaler plan moves and straggler cuts are instant events on
+    the same log. Under a ``jax.profiler`` session the host spans are also
+    profiler annotations, and the step's ``jax.named_scope``s
+    (``fl_client``, ``fl_uplink_codec``, ``fl_downlink_codec``,
+    ``fl_server``, ``fl_optimizer``) name the layer of each device
+    operation. Jitted steps return metrics as device arrays through aux
+    pytrees into an `obs.MetricsBuffer`, converted with ONE
     ``jax.device_get`` at the end of the run — tests/test_obs.py counts
     transfers to hold instrumented runs to "no more than
     uninstrumented". Export with ``Recorder.write_jsonl`` (append-only

@@ -179,15 +179,18 @@ class StackedExecutor(CohortExecutor):
                       mode="sync" if weights is None else "weighted"):
             if weights is None:
                 # one definition of the bitwise-critical batch fusing
-                batch = self.trainer.stack_batches(parts)
-                if cut_state is None:
-                    return self._step(state, batch)
-                return self._step(state, batch, cut_state)
-            batches = _stack_parts(parts)
-            w = jnp.asarray(weights, jnp.float32)
-            if cut_state is None:
-                return self._weighted_step(state, batches, w)
-            return self._weighted_step(state, batches, w, cut_state)
+                step = self._step
+                args = (state, self.trainer.stack_batches(parts))
+            else:
+                step = self._weighted_step
+                args = (state, _stack_parts(parts),
+                        jnp.asarray(weights, jnp.float32))
+            if cut_state is not None:
+                args += (cut_state,)
+            # only the call of the jitted step: everything before it is
+            # round assembly
+            with obs.span("executor.dispatch", cat="executor"):
+                return step(*args)
 
 
 @dataclasses.dataclass
@@ -311,8 +314,9 @@ class MeshExecutor(CohortExecutor):
                       clients=n, slots=slots, shards=self.num_shards,
                       mode="sync" if sync else "weighted"):
             step = self._get_step("cohort" if sync else "client")
-            state, metrics = step(*self._placed_args(state, parts, weights,
-                                                     cut_state))
+            args = self._placed_args(state, parts, weights, cut_state)
+            with obs.span("executor.dispatch", cat="executor"):
+                state, metrics = step(*args)
             if sync:
                 # keep synchronous metrics key-compatible with the stacked
                 # path

@@ -367,9 +367,11 @@ class FederatedTrainer:
 
         The step donates ``state``: rebind it to the returned state and do
         not read the one passed in (copy it first to keep it)."""
-        ids = sample_clients(self._rng, self.data.num_clients, self.cohort)
-        parts = [self.client_batch_for(cid, key) for cid in ids]
-        return self.executor.execute(state, parts)
+        with obs.span("trainer.round", cat="trainer"):
+            ids = sample_clients(self._rng, self.data.num_clients,
+                                 self.cohort)
+            parts = [self.client_batch_for(cid, key) for cid in ids]
+            return self.executor.execute(state, parts)
 
     # ---- cross-round cut-layer state ---------------------------------------
     def _client_act_struct(self, params, part):

@@ -117,9 +117,33 @@ def _maybe_quantize(x, pq: Optional[PQConfig], lam, quantize: bool,
     return zt, stats
 
 
+def _split_loss(model, params: Params, batch, head, quantize: bool,
+                lam_override, key, cut_state):
+    """The split forward shared by the paper models: client half, the cut
+    codecs, server half and ``head(logits, batch)``, each under the named
+    scope that attributes its device operations (and their transposes) to
+    one layer of the server update. Returns (loss, codec stats)."""
+    with jax.named_scope("fl_client"):
+        acts = model.client_forward(params["client"], batch)
+    with jax.named_scope("fl_uplink_codec"):
+        acts, stats = _maybe_quantize(acts, model.pq, model.lam, quantize,
+                                       model.client_batch, lam_override,
+                                       model.downlink_compressor,
+                                       key=key, cut_state=cut_state)
+    with jax.named_scope("fl_server"):
+        loss = head(model.server_logits(params["server"], acts), batch)
+    return loss, stats
+
+
 # ---------------------------------------------------------------------------
 # FEMNIST CNN
 # ---------------------------------------------------------------------------
+
+def _ce_head(logits, batch):
+    labels = batch["label"]
+    return -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(labels.shape[0]),
+                                                labels])
+
 
 @dataclasses.dataclass(frozen=True)
 class FemnistCNN:
@@ -168,15 +192,8 @@ class FemnistCNN:
 
     def loss(self, params: Params, batch, *, quantize: bool = True,
              lam_override=None, key=None, cut_state=None):
-        acts = self.client_forward(params["client"], batch)
-        acts, stats = _maybe_quantize(acts, self.pq, self.lam, quantize,
-                                       self.client_batch, lam_override,
-                                       self.downlink_compressor,
-                                       key=key, cut_state=cut_state)
-        logits = self.server_logits(params["server"], acts)
-        labels = batch["label"]
-        ce = -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(labels.shape[0]),
-                                                  labels])
+        ce, stats = _split_loss(self, params, batch, _ce_head, quantize,
+                                lam_override, key, cut_state)
         return ce, dict(stats, ce=ce)
 
     def accuracy(self, params: Params, batch) -> jax.Array:
@@ -188,6 +205,12 @@ class FemnistCNN:
 # ---------------------------------------------------------------------------
 # SO Tag MLP (multi-label)
 # ---------------------------------------------------------------------------
+
+def _bce_head(logits, batch):
+    y = batch["tags"].astype(jnp.float32)  # (B, num_tags) multi-hot
+    return jnp.mean(jnp.maximum(logits, 0) - logits * y +
+                    jnp.log1p(jnp.exp(-jnp.abs(logits))))
+
 
 @dataclasses.dataclass(frozen=True)
 class SOTagMLP:
@@ -217,15 +240,8 @@ class SOTagMLP:
 
     def loss(self, params, batch, *, quantize: bool = True,
              lam_override=None, key=None, cut_state=None):
-        acts = self.client_forward(params["client"], batch)
-        acts, stats = _maybe_quantize(acts, self.pq, self.lam, quantize,
-                                       self.client_batch, lam_override,
-                                       self.downlink_compressor,
-                                       key=key, cut_state=cut_state)
-        logits = self.server_logits(params["server"], acts)
-        y = batch["tags"].astype(jnp.float32)  # (B, num_tags) multi-hot
-        bce = jnp.mean(jnp.maximum(logits, 0) - logits * y +
-                       jnp.log1p(jnp.exp(-jnp.abs(logits))))
+        bce, stats = _split_loss(self, params, batch, _bce_head, quantize,
+                                 lam_override, key, cut_state)
         return bce, dict(stats, bce=bce)
 
     def recall_at_5(self, params, batch):
@@ -240,6 +256,15 @@ class SOTagMLP:
 # ---------------------------------------------------------------------------
 # SO NWP LSTM
 # ---------------------------------------------------------------------------
+
+def _token_ce_head(logits, batch):
+    labels = batch["labels"]  # (B, S), -1 = ignore
+    mask = labels >= 0
+    safe = jnp.maximum(labels, 0)
+    lp = jax.nn.log_softmax(logits)
+    ce = -jnp.sum(jnp.take_along_axis(lp, safe[..., None], -1)[..., 0] * mask)
+    return ce / jnp.maximum(mask.sum(), 1)
+
 
 @dataclasses.dataclass(frozen=True)
 class SONwpLSTM:
@@ -292,18 +317,8 @@ class SONwpLSTM:
 
     def loss(self, params, batch, *, quantize: bool = True,
              lam_override=None, key=None, cut_state=None):
-        acts = self.client_forward(params["client"], batch)
-        acts, stats = _maybe_quantize(acts, self.pq, self.lam, quantize,
-                                       self.client_batch, lam_override,
-                                       self.downlink_compressor,
-                                       key=key, cut_state=cut_state)
-        logits = self.server_logits(params["server"], acts)
-        labels = batch["labels"]  # (B, S), -1 = ignore
-        mask = labels >= 0
-        safe = jnp.maximum(labels, 0)
-        lp = jax.nn.log_softmax(logits)
-        ce = -jnp.sum(jnp.take_along_axis(lp, safe[..., None], -1)[..., 0] * mask)
-        ce = ce / jnp.maximum(mask.sum(), 1)
+        ce, stats = _split_loss(self, params, batch, _token_ce_head,
+                                quantize, lam_override, key, cut_state)
         return ce, dict(stats, ce=ce)
 
     def accuracy(self, params, batch):

@@ -385,13 +385,19 @@ class TransformerLM:
     def loss(self, params: Params, batch, *, quantize: bool = True,
              lam_override=None, key=None, cut_state=None):
         """Full FedLite forward: client -> PQ (+corrected VJP) -> server -> CE."""
-        acts, _, aux_c = self.client_forward(params["client"], batch, mode="train")
-        acts, pq_stats = self.cut_activation(acts, quantize=quantize,
-                                             lam_override=lam_override,
-                                             key=key, cut_state=cut_state)
-        x, _, aux_s = self.server_forward(params["server"], acts, batch,
-                                          mode="train")
-        ce = self.chunked_ce(params, x, batch["labels"])
+        # named scopes attribute the step's device operations (and their
+        # transposes) to the layers of a server update
+        with jax.named_scope("fl_client"):
+            acts, _, aux_c = self.client_forward(params["client"], batch,
+                                                 mode="train")
+        with jax.named_scope("fl_uplink_codec"):
+            acts, pq_stats = self.cut_activation(acts, quantize=quantize,
+                                                 lam_override=lam_override,
+                                                 key=key, cut_state=cut_state)
+        with jax.named_scope("fl_server"):
+            x, _, aux_s = self.server_forward(params["server"], acts, batch,
+                                              mode="train")
+            ce = self.chunked_ce(params, x, batch["labels"])
         metrics = {"ce": ce, "aux": aux_c + aux_s, **pq_stats}
         return ce + aux_c + aux_s, metrics
 

@@ -5,15 +5,18 @@ Three pillars, one event log:
   spans.py   — `span`/`virtual_span`/`event`/`instrument` record host
                wall-clock and the scheduler's simulated clock as parallel
                lanes into a module-level `Recorder` (`configure` installs
-               one; everything is a no-op otherwise, and inside jit
-               tracing). The hot path is permanently instrumented:
-               scheduler rounds, executor execute/place, wire
-               encode/decode, Lloyd/kmeans, checkpoint save/restore.
-  metrics.py — `MetricsBuffer` plus jit-safe `counter`/`gauge`/`histogram`
-               helpers: metrics accumulate as arrays inside jitted steps
-               and ride the existing aux pytrees; the host records them
-               without looking and flushes the whole run with exactly one
-               ``jax.device_get`` — instrumentation adds zero host syncs.
+               one). While a ``jax.profiler`` session collects, `span` and
+               `instrument` also emit a ``TraceAnnotation`` of the span's
+               name, on the device trace's clock. Everything is a no-op
+               otherwise, and inside jit tracing. The hot path is
+               permanently instrumented: ``trainer.round``, executor
+               execute/place/dispatch, scheduler rounds, wire
+               encode/decode, checkpoint save/restore.
+  metrics.py — `MetricsBuffer`: metrics are plain arrays computed inside
+               jitted steps that ride the existing aux pytrees; the host
+               records them without looking and flushes the whole run with
+               exactly one ``jax.device_get`` — instrumentation adds zero
+               host syncs.
   export.py  — append-only JSONL event logs and Chrome/Perfetto
                ``trace_event`` JSON (host and virtual lanes render as two
                processes with per-category tracks).
@@ -57,7 +60,7 @@ from repro.obs.flight import (
     log_frames,
     set_flights,
 )
-from repro.obs.metrics import MetricsBuffer, counter, gauge, histogram
+from repro.obs.metrics import MetricsBuffer
 from repro.obs.slo import (
     DEFAULT_SLOS,
     HealthMonitor,
@@ -116,8 +119,8 @@ def log_trace(trace, run=None) -> None:
 
 __all__ = [
     "DEFAULT_SLOS", "FlightFrame", "HealthMonitor", "MetricsBuffer",
-    "Recorder", "SloRule", "configure", "counter", "current", "enabled",
-    "event", "flights_enabled", "gauge", "histogram", "instrument",
+    "Recorder", "SloRule", "configure", "current", "enabled",
+    "event", "flights_enabled", "instrument",
     "jsonable", "log_frames", "log_trace", "parse_rule", "read_jsonl",
     "read_jsonl_tolerant", "set_flights", "shutdown", "span",
     "to_perfetto", "virtual_span", "write_jsonl", "write_perfetto",

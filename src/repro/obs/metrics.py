@@ -1,9 +1,8 @@
 """Sync-free in-jit metrics: device-side accumulation, one flush per run.
 
-The contract: jitted steps compute metrics as arrays inside the trace
-(``counter``/``gauge``/``histogram`` below are jit-safe helpers) and return
-them through their existing aux pytrees. The host side *records* those
-device values without looking at them — `MetricsBuffer.record` is just a
+The contract: jitted steps compute metrics as plain arrays inside the trace
+and return them through their existing aux pytrees. The host side
+*records* those device values without looking at them — `MetricsBuffer.record` is just a
 list append, adding **zero** device→host syncs to the hot loop — and
 converts them all at once at the end of the run with a single
 ``jax.device_get`` in `MetricsBuffer.flush`. tests/test_obs.py counts
@@ -15,31 +14,7 @@ from __future__ import annotations
 from typing import Any, Dict, List
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-
-def counter(x) -> jnp.ndarray:
-    """Sum a (possibly batched) quantity into a scalar count, in-trace."""
-    return jnp.sum(jnp.asarray(x, jnp.float32))
-
-
-def gauge(x) -> jnp.ndarray:
-    """A point-in-time scalar reading, in-trace."""
-    return jnp.asarray(x, jnp.float32).reshape(())
-
-
-def histogram(x, bins: int = 16, lo: float = 0.0,
-              hi: float = 1.0) -> jnp.ndarray:
-    """Fixed-range histogram counts with a static shape, jit-safe.
-
-    ``bins``/``lo``/``hi`` must be Python constants (they size the output).
-    Values outside [lo, hi] clamp into the edge buckets so no sample is
-    silently dropped."""
-    x = jnp.asarray(x, jnp.float32).reshape(-1)
-    edges = jnp.linspace(lo, hi, bins + 1)
-    idx = jnp.clip(jnp.searchsorted(edges, x, side="right") - 1, 0, bins - 1)
-    return jnp.zeros((bins,), jnp.float32).at[idx].add(1.0)
 
 
 def _host_value(v: Any) -> Any:
@@ -54,8 +29,7 @@ class MetricsBuffer:
 
     ``record`` keeps device arrays as-is (no sync); ``flush`` performs the
     run's single blocking ``jax.device_get`` over everything recorded and
-    returns per-round dicts of host floats (lists for vector metrics such
-    as histograms)."""
+    returns per-round dicts of host floats (lists for vector metrics)."""
 
     def __init__(self) -> None:
         self._pending: List[Dict[str, Any]] = []

@@ -2,10 +2,10 @@
 
 A `Recorder` collects plain-dict events; ``span``/``virtual_span``/``event``
 are the module-level entry points the hot path calls. When no recorder is
-configured (the default) every entry point is a near-zero-cost no-op, so
-instrumentation can live permanently in `Scheduler.run`, the executors, the
-wire codec, Lloyd/kmeans and checkpoint I/O without taxing uninstrumented
-runs.
+configured and no profiler session is collecting (the default) every entry
+point is a near-zero-cost no-op, so instrumentation can live permanently in
+the round driver, `Scheduler.run`, the executors, the wire codec and
+checkpoint I/O without taxing uninstrumented runs.
 
 Two time lanes, recorded side by side:
 
@@ -15,6 +15,12 @@ Two time lanes, recorded side by side:
              device→host syncs).
   * virtual — the scheduler's simulated clock (``virtual_span``); what the
              modeled fleet spent.
+
+While a ``jax.profiler`` session is collecting, ``span`` and ``instrument``
+also enter a ``jax.profiler.TraceAnnotation`` of the bare span name, with or
+without a recorder: the span then lands in the profiler's trace on the same
+clock as the device's operations. Its ``args`` go to the recorder only
+(keyword metadata would change the event's name in the trace).
 
 Spans are trace-safe: inside jit tracing (``jax.core.trace_state_clean()``
 is False) every entry point degrades to a no-op, so a span in a function
@@ -38,6 +44,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 try:  # the in-trace guard; location varies across jax versions
     from jax.core import trace_state_clean as _trace_state_clean
 except ImportError:  # pragma: no cover - newer jax moved it
@@ -46,6 +54,9 @@ except ImportError:  # pragma: no cover - newer jax moved it
     except ImportError:  # pragma: no cover - jax absent or relocated again
         def _trace_state_clean() -> bool:
             return True
+
+# True while a profiler session is collecting (one cheap native call)
+_profiling = TraceAnnotation.is_enabled
 
 
 class Recorder:
@@ -105,18 +116,24 @@ class Recorder:
 
 
 class _Span:
-    """Host-lane span context manager (created only when recording)."""
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    """Host-lane span context manager (created only when recording or
+    profiling): a recorder event, a profiler annotation, or both."""
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_ann")
 
-    def __init__(self, rec: Recorder, name: str, cat: str, args: Dict):
+    def __init__(self, rec: Optional[Recorder], name: str, cat: str,
+                 args: Dict, profiling: bool):
         self._rec = rec
         self.name = name
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._ann = TraceAnnotation(name) if profiling else None
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._rec.now()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = self._rec.now()
         return self
 
     def set(self, **args) -> None:
@@ -124,9 +141,13 @@ class _Span:
         self.args.update(args)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self._rec.append({"type": "span", "lane": "host", "name": self.name,
-                          "cat": self.cat, "t0": self._t0,
-                          "t1": self._rec.now(), "args": self.args})
+        if self._rec is not None:
+            self._rec.append({"type": "span", "lane": "host",
+                              "name": self.name, "cat": self.cat,
+                              "t0": self._t0, "t1": self._rec.now(),
+                              "args": self.args})
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -173,11 +194,13 @@ def enabled() -> bool:
 
 
 def span(name: str, cat: str = "app", **args):
-    """Host-lane span context manager; a no-op when disabled or tracing."""
-    rec = _RECORDER
-    if rec is None or not _trace_state_clean():
+    """Host-lane span context manager, also a profiler annotation while a
+    profiler session collects; a no-op when neither records, or inside jit
+    tracing."""
+    rec, profiling = _RECORDER, _profiling()
+    if (rec is None and not profiling) or not _trace_state_clean():
         return _NULL_SPAN
-    return _Span(rec, name, cat, args)
+    return _Span(rec, name, cat, args, profiling)
 
 
 def virtual_span(name: str, t_start: float, t_end: float,
@@ -208,7 +231,8 @@ def instrument(name: Optional[str] = None,
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if _RECORDER is None or not _trace_state_clean():
+            if (_RECORDER is None and not _profiling()) \
+                    or not _trace_state_clean():
                 return fn(*args, **kwargs)
             with span(label, cat=cat):
                 return fn(*args, **kwargs)

@@ -157,6 +157,62 @@ def test_instrument_wrapper_records_per_call():
     assert fn.__name__ == "fn"                    # functools.wraps preserved
 
 
+def _profiled_host_events(tmp_path, fn):
+    """Run ``fn`` under a profiler session; the names of the host events
+    in the ``.xplane.pb`` it wrote."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    return [e.name for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_span_is_a_profiler_annotation_without_recorder(tmp_path):
+    def work():
+        with obs.span("obs.test.span", cat="test"):
+            pass
+
+    names = _profiled_host_events(tmp_path, work)
+    assert names.count("obs.test.span") == 1
+    assert obs.current() is None
+
+
+def test_span_args_stay_out_of_the_annotation_name(tmp_path):
+    rec = obs.configure(run="t")
+
+    @obs.instrument("obs.test.fn", cat="test")
+    def fn():
+        return 1
+
+    def work():
+        with obs.span("obs.test.args", cat="test", n=3, mode="sync") as sp:
+            sp.set(extra=1)
+        fn()
+
+    names = _profiled_host_events(tmp_path, work)
+    assert [n for n in names if n.startswith("obs.test.")] == \
+        ["obs.test.args", "obs.test.fn"]
+    spans = [e for e in rec.events if e["type"] == "span"]
+    assert spans[0]["args"] == {"n": 3, "mode": "sync", "extra": 1}
+
+
+def test_no_annotation_while_jit_tracing(tmp_path):
+    @jax.jit
+    def f(x):
+        with obs.span("obs.test.traced", cat="test"):
+            return x * 2
+
+    names = _profiled_host_events(
+        tmp_path, lambda: f(jnp.ones(3)).block_until_ready())
+    assert "obs.test.traced" not in names
+    assert "PjitFunction(f)" in names             # the session saw the call
+
+
 # ---------------------------------------------------------------------------
 # exporters: JSONL append-only round-trip + Perfetto structure
 # ---------------------------------------------------------------------------
@@ -212,21 +268,26 @@ def test_perfetto_two_lanes_and_phases(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_metric_helpers_inside_jit():
+    """Metrics computed as plain arrays inside a jitted step are recorded
+    without a sync and flushed in one transfer: scalars as floats, vectors
+    as lists."""
     @jax.jit
     def step(x):
-        return {"n": obs.counter(jnp.ones_like(x)),
-                "mean": obs.gauge(x.mean()),
-                "hist": obs.histogram(x, bins=4, lo=0.0, hi=1.0)}
+        return {"n": jnp.sum(jnp.ones_like(x)),
+                "mean": x.mean(),
+                "hist": jnp.zeros(4).at[jnp.clip(
+                    (x * 4).astype(jnp.int32), 0, 3)].add(1.0)}
 
     buf = obs.MetricsBuffer()
     buf.record(step(jnp.array([0.1, 0.3, 0.6, 0.9])))
-    buf.record(step(jnp.array([-1.0, 2.0])))      # out-of-range clamps
+    buf.record(step(jnp.array([-1.0, 2.0])))
     assert len(buf) == 2
     out = buf.flush()
     assert len(buf) == 0
     assert out[0]["n"] == 4.0 and isinstance(out[0]["n"], float)
+    assert out[0]["mean"] == pytest.approx(0.475)
     assert out[0]["hist"] == [1.0, 1.0, 1.0, 1.0]
-    assert out[1]["hist"] == [1.0, 0.0, 0.0, 1.0]  # edge buckets
+    assert out[1]["hist"] == [1.0, 0.0, 0.0, 1.0]
     assert buf.flush() == []                       # idempotent when drained
 
 

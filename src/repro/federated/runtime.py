@@ -155,6 +155,30 @@ def run_fedavg(model, params, data: FederatedDataset, *, rounds: int,
 # SplitFed / FedLite trainer
 # ---------------------------------------------------------------------------
 
+@jax.jit
+def _cohort_keys(round_key: jax.Array, cids: jax.Array,
+                 versions: Optional[jax.Array] = None):
+    """Every participant's PRNG key in one program.
+
+    Key ``i`` is ``fold_in(round_key, cids[i])``, or, with ``versions``,
+    ``fold_in(fold_in(round_key, versions[i] + 1), cids[i])`` (the scheduled
+    run's key of the model version the client trained against): bitwise
+    what the per-client eager ``fold_in``s give. Returns a tuple of keys,
+    one dispatch with N outputs: indexing one (N, 2) array on the host
+    would be N eager ops again. Retraces per cohort size."""
+    fold = jax.vmap(jax.random.fold_in, in_axes=(None, 0))
+    keys = fold(round_key, cids) if versions is None \
+        else jax.vmap(jax.random.fold_in)(fold(round_key, versions + 1), cids)
+    return tuple(keys)
+
+
+@jax.jit
+def _concat_cohort(parts):
+    """The cohort's client batches joined along axis 0, every leaf in one
+    program (retraces per cohort size and tree structure)."""
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+
+
 @dataclasses.dataclass
 class FederatedTrainer:
     """Round driver for split-learning algorithms on a FederatedDataset.
@@ -346,19 +370,29 @@ class FederatedTrainer:
         return TrainState.create(self.model.init(key), self.optimizer)
 
     # ---- batch assembly ----------------------------------------------------
-    def client_batch_for(self, cid: int, round_key: jax.Array):
-        return self.data.sample_batch(int(cid),
-                                      jax.random.fold_in(round_key, int(cid)),
-                                      self.client_batch,
+    def _sample(self, cid: int, client_key: jax.Array):
+        return self.data.sample_batch(int(cid), client_key, self.client_batch,
                                       **(self.batch_kwargs or {}))
 
+    def client_batch_for(self, cid: int, round_key: jax.Array):
+        return self._sample(cid, jax.random.fold_in(round_key, int(cid)))
+
+    def _cohort_parts(self, cids: Sequence[int], round_key: jax.Array,
+                      versions: Optional[Sequence[int]] = None):
+        """One batch per client, in ``cids`` order, each sampled with the
+        key `client_batch_for` would give it (with ``versions``, the key of
+        that version's round); all keys come from one `_cohort_keys` call."""
+        keys = _cohort_keys(round_key, np.asarray(cids, np.uint32),
+                            None if versions is None
+                            else np.asarray(versions, np.uint32))
+        return [self._sample(c, k) for c, k in zip(cids, keys)]
+
     def stack_batches(self, parts: Sequence[Dict[str, jax.Array]]):
-        return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+        return _concat_cohort(parts)
 
     def cohort_batch(self, key: jax.Array) -> Dict[str, jax.Array]:
         ids = sample_clients(self._rng, self.data.num_clients, self.cohort)
-        return self.stack_batches([self.client_batch_for(cid, key)
-                                   for cid in ids])
+        return self.stack_batches(self._cohort_parts(ids, key))
 
     def round(self, state: TrainState, key: jax.Array):
         """One synchronous server update on a fresh cohort, through the
@@ -370,8 +404,7 @@ class FederatedTrainer:
         with obs.span("trainer.round", cat="trainer"):
             ids = sample_clients(self._rng, self.data.num_clients,
                                  self.cohort)
-            parts = [self.client_batch_for(cid, key) for cid in ids]
-            return self.executor.execute(state, parts)
+            return self.executor.execute(state, self._cohort_parts(ids, key))
 
     # ---- cross-round cut-layer state ---------------------------------------
     def _client_act_struct(self, params, part):
@@ -716,12 +749,8 @@ class FederatedTrainer:
         def execute(update_idx: int, participants: Sequence[Arrival],
                     weights: Sequence[float]) -> Dict:
             nonlocal state
-            round_keys = {}
-            parts = []
-            for a in participants:
-                rk = round_keys.setdefault(
-                    a.version, jax.random.fold_in(key, a.version + 1))
-                parts.append(self.client_batch_for(a.client, rk))
+            parts = self._cohort_parts([a.client for a in participants], key,
+                                       [a.version for a in participants])
             if inj is not None and parts:
                 participants, parts, weights, fl = self._screen_cohort(
                     inj, update_idx, participants, parts, weights)

@@ -35,6 +35,14 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     sliding_window: Optional[int] = None    # tokens; None = full attention
     mrope_sections: Optional[Tuple[int, int, int]] = None  # VLM M-RoPE (t,h,w)
+    # "gqa" (grouped-query heads of head_dim) or "mla" (DeepSeek-V3 latent
+    # attention: keys and values up-projected from a kv_lora_rank latent;
+    # head_dim is the query/key width, qk_rope_dim of it rotated on
+    # interleaved pairs with one key shared by all heads, v_head_dim values)
+    attn_kind: str = "gqa"
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0               # 0 = head_dim
     # mlp ------------------------------------------------------------------
     d_ff: int = 0
     mlp_type: str = "swiglu"          # swiglu | geglu | gelu
@@ -48,6 +56,21 @@ class ArchConfig:
     moe_offset: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # "capacity": softmax top-k over all experts, tokens over capacity are
+    # dropped (models/moe.apply_moe). "dropless": DeepSeek-V3's layer —
+    # sigmoid scores, top-k by score + a fixed correction bias, weights
+    # renormalized and scaled by routed_scaling; the layer holds experts
+    # [expert_offset, expert_offset + experts_held) of num_experts and
+    # computes their part for every token routed to them, plus
+    # num_shared_experts shared experts (models/moe.apply_dropless_moe)
+    moe_layer: str = "capacity"
+    moe_d_ff: int = 0                 # routed expert width; 0 = d_ff
+    experts_held: int = 0             # 0 = all of num_experts
+    expert_offset: int = 0
+    num_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    first_dense_layers: int = 0       # leading layers with a dense FFN: 0
+    #                                   or the client's whole depth
     # SSM / hybrid -------------------------------------------------------
     layer_pattern: Tuple[str, ...] = ("attn",)   # repeated block pattern
     ssm_state: int = 0
@@ -99,6 +122,20 @@ class ArchConfig:
             raise ValueError(f"{self.name}: pattern period must contain whole moe periods")
         if self.num_periods <= self.cut_periods:
             raise ValueError(f"{self.name}: cut_periods must leave server layers")
+        # each half's layers are scanned alike, so the leading dense layers
+        # are none or exactly the client's
+        if self.first_dense_layers not in (0, self.cut_periods * period):
+            raise ValueError(f"{self.name}: first_dense_layers must be 0 or "
+                             f"the client's {self.cut_periods * period} layers")
+        if self.attn_kind not in ("gqa", "mla"):
+            raise ValueError(f"{self.name}: attn_kind {self.attn_kind!r}")
+        if self.moe_layer not in ("capacity", "dropless"):
+            raise ValueError(f"{self.name}: moe_layer {self.moe_layer!r}")
+        if self.num_experts and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.held_experts <= self.num_experts):
+            raise ValueError(f"{self.name}: held experts outside "
+                             f"0..{self.num_experts}")
 
     @property
     def period(self) -> int:
@@ -121,6 +158,18 @@ class ArchConfig:
         return self.num_kv_heads * self.head_dim
 
     @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -132,7 +181,9 @@ class ArchConfig:
         return self.layer_pattern[pos % self.period]
 
     def is_moe_layer(self, layer_idx: int) -> bool:
-        return bool(self.num_experts) and (layer_idx % self.moe_period == self.moe_offset)
+        return bool(self.num_experts) and \
+            layer_idx >= self.first_dense_layers and \
+            (layer_idx % self.moe_period == self.moe_offset)
 
     @property
     def compute_dtype(self):
@@ -149,7 +200,12 @@ class ArchConfig:
             n += self.vision_embed_dim * D
         for i in range(self.num_layers):
             kind = self.block_kind(i)
-            if kind == "attn":
+            if kind == "attn" and self.attn_kind == "mla":
+                r, H = self.kv_lora_rank, self.num_heads
+                n += D * self.q_dim + D * (r + self.qk_rope_dim) + r \
+                    + r * H * (self.head_dim - self.qk_rope_dim + self.v_dim) \
+                    + H * self.v_dim * D
+            elif kind == "attn":
                 n += D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
             else:  # ssm
                 din, N, H = self.d_inner, self.ssm_state, self.ssm_heads
@@ -157,7 +213,9 @@ class ArchConfig:
                 n += D * proj_out + din * D + self.ssm_conv_width * (din + 2 * N)
             if self.is_moe_layer(i):
                 e = self.experts_per_token if active_only else self.num_experts
-                n += e * (3 if self.mlp_type in ("swiglu", "geglu") else 2) * D * F
+                e += self.num_shared_experts
+                n += e * (3 if self.mlp_type in ("swiglu", "geglu") else 2) \
+                    * D * self.expert_d_ff
                 n += D * self.num_experts  # router
             elif F:
                 n += (3 if self.mlp_type in ("swiglu", "geglu") else 2) * D * F
@@ -196,6 +254,7 @@ ARCH_IDS = [
     "musicgen_large",
     "llama3_8b",
     "command_r_35b",
+    "moonlight_16b_a3b",
 ]
 
 # archs whose long_500k decode is skipped (pure full attention; see DESIGN.md)

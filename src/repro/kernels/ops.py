@@ -8,7 +8,9 @@ is a TPU (there is no path that interprets on the chip).
 Every block a wrapper hands a kernel is legal for Mosaic: the PQ kernels
 take the points on lanes (``x.T``, see ``kmeans_assign.py``) in blocks of a
 multiple of 128 lanes; the scalar-quantize kernel takes (8k, 128m) tiles or
-the whole (padded) dimension. The public signatures stay row-major (N, D).
+the whole (padded) dimension; the grouped matmuls take row tiles of 512 (or
+all rows, padded to 8) and column tiles of 512 or the whole dimension. The
+public signatures stay row-major (N, D).
 """
 
 from __future__ import annotations
@@ -19,6 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.kmeans_assign import kmeans_assign_kernel
+from repro.kernels.moe_gmm import (group_metadata, moe_gmm_kernel,
+                                   moe_tgmm_kernel)
 from repro.kernels.pq_quantize import pq_quantize_kernel
 
 LANE = 128
@@ -28,6 +32,11 @@ SCORE_TILE_ELEMS = 1 << 19
 # scalar-quantize column tile: three (256, 1024) f32/int32 blocks, double-
 # buffered, take 6 MiB of scoped VMEM whatever the row width
 SCALARQ_COL_BLOCK = 1024
+# grouped matmul tiles: (512, 512) bf16 operand blocks with a (512, <=2048)
+# f32 accumulator stay within the default scoped VMEM, double-buffered
+GMM_ROW_BLOCK = 512
+GMM_COL_BLOCK = 512
+GMM_WHOLE_DIM = 2048
 
 
 def _interpret_default() -> bool:
@@ -224,3 +233,96 @@ def flash_attention(q, k, v, *, num_q_heads: int, num_kv_heads: int,
               block_q=min(block_q, q.shape[1]),
               block_k=min(block_k, q.shape[1]), interpret=interpret)
     return out[:, :s]
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul over the experts a layer holds
+# ---------------------------------------------------------------------------
+
+def _gmm_col_tile(dim: int) -> int:
+    """A column tile: GMM_COL_BLOCK where it divides ``dim``, else the whole
+    dimension up to GMM_WHOLE_DIM, else the largest dividing multiple of
+    128 below GMM_COL_BLOCK."""
+    if dim % GMM_COL_BLOCK == 0:
+        return GMM_COL_BLOCK
+    if dim <= GMM_WHOLE_DIM:
+        return dim
+    t = GMM_COL_BLOCK // LANE * LANE
+    while t >= LANE and dim % t:
+        t -= LANE
+    if t < LANE:
+        raise ValueError(f"no 128-multiple tile divides {dim}")
+    return t
+
+
+def _gmm_rows(lhs, group_sizes, visit_empty):
+    """Pad the rows to the row tile; the grid's metadata for them."""
+    tm = min(GMM_ROW_BLOCK, _round_up(lhs.shape[0], SUBLANE))
+    lhs = _pad_axis(lhs, tm, 0)
+    meta, num_tiles = group_metadata(group_sizes.astype(jnp.int32),
+                                     lhs.shape[0], tm, visit_empty)
+    return lhs, tm, meta, num_tiles
+
+
+def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    lhs, tm, meta, num_tiles = _gmm_rows(lhs, group_sizes, False)
+    out = moe_gmm_kernel(*meta, num_tiles, lhs, rhs,
+                         tiling=(tm, _gmm_col_tile(k), _gmm_col_tile(n)),
+                         transpose_rhs=transpose_rhs, interpret=interpret)
+    return out[:m]
+
+
+def _tgmm(lhs, rhs, group_sizes, out_dtype, interpret):
+    lhs, tm, meta, num_tiles = _gmm_rows(lhs, group_sizes, True)
+    rhs = _pad_axis(rhs, tm, 0)
+    return moe_tgmm_kernel(
+        *meta, num_tiles, lhs, rhs, num_groups=group_sizes.shape[0],
+        tiling=(tm, _gmm_col_tile(lhs.shape[1]), _gmm_col_tile(rhs.shape[1])),
+        out_dtype=out_dtype, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_pallas(lhs, rhs, group_sizes, interpret):
+    return _gmm(lhs, rhs, group_sizes, False, interpret)
+
+
+def _gmm_pallas_fwd(lhs, rhs, group_sizes, interpret):
+    return _gmm(lhs, rhs, group_sizes, False, interpret), \
+        (lhs, rhs, group_sizes)
+
+
+def _gmm_pallas_bwd(interpret, res, g):
+    lhs, rhs, group_sizes = res
+    g = g.astype(lhs.dtype)
+    dlhs = _gmm(g, rhs, group_sizes, True, interpret)
+    drhs = _tgmm(lhs, g, group_sizes, rhs.dtype, interpret)
+    return dlhs, drhs, None
+
+
+_gmm_pallas.defvjp(_gmm_pallas_fwd, _gmm_pallas_bwd)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   *, backend: str = "auto",
+                   interpret: bool | None = None) -> jax.Array:
+    """Row r of group g times rhs[g]: lhs (m, k) with its rows sorted by
+    group, group_sizes (G,) int32 summing to at most m, rhs (G, k, n) ->
+    (m, n) in lhs's dtype, accumulated in float32. Differentiable in lhs
+    and rhs.
+
+    Rows past the last group are zero on the ``jnp`` path
+    (``jax.lax.ragged_dot``) and left unwritten by the Pallas kernels
+    (``kernels/moe_gmm.py``), whose work follows the rows the groups hold:
+    the caller masks them. ``auto`` takes the kernels on a TPU."""
+    if backend == "auto":
+        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    if backend == "jnp":
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes.astype(jnp.int32),
+            preferred_element_type=jnp.float32).astype(lhs.dtype)
+    if backend != "pallas":
+        raise ValueError(f"grouped matmul backend {backend!r}")
+    interpret = _interpret_default() if interpret is None else interpret
+    return _gmm_pallas(lhs, rhs, group_sizes.astype(jnp.int32), interpret)

@@ -1,4 +1,5 @@
-"""Grouped-query attention with RoPE/M-RoPE, three execution paths:
+"""Grouped-query attention with RoPE/M-RoPE, and DeepSeek-V3's latent
+attention (MLA), over three execution paths:
 
   * ``row_block``: causal (optionally windowed) attention computed in query
     row-blocks via ``lax.scan`` — peak memory O(q_chunk · S_kv) instead of
@@ -11,6 +12,14 @@
 
 KV caches are dicts {k, v, pos}; ``pos`` records the absolute position held
 in each slot so windowed ring buffers and full caches share one code path.
+
+MLA (``cfg.attn_kind == "mla"``, arXiv:2412.19437 §2.1.1, without the query
+latent): q = x·W_q splits per head into q_nope and q_pe; [c_kv, k_pe] =
+x·W_kva, c_kv is RMS-normalized and up-projected to [k_nope, v] per head;
+q_pe and the single k_pe (shared by every head) are rotated on interleaved
+pairs. Keys are [k_nope, k_pe] of ``head_dim`` and values ``v_head_dim``
+wide, so the paths take the value width from v; the cache holds the
+expanded per-head keys and values.
 """
 
 from __future__ import annotations
@@ -22,8 +31,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init, row
-from repro.models.rope import apply_rope, rope_angles
+from repro.models.layers import apply_norm, dense_init, norm_init, row
+from repro.models.rope import apply_rope, apply_rope_interleaved, rope_angles
 from repro.sharding import shard, shard_residual
 
 NEG_INF = -1e30
@@ -35,6 +44,8 @@ NEG_INF = -1e30
 
 def attn_init(key, cfg, dtype):
     ks = jax.random.split(key, 4)
+    if cfg.attn_kind == "mla":
+        return _mla_init(ks, cfg, dtype)
     D, Q, KV = cfg.d_model, cfg.q_dim, cfg.kv_dim
     p = {
         "wq": dense_init(ks[0], D, Q, dtype),
@@ -48,6 +59,36 @@ def attn_init(key, cfg, dtype):
         p["wv_b"] = jnp.zeros((KV,), dtype)
         p["wo_b"] = jnp.zeros((D,), dtype)
     return p
+
+
+def _mla_init(ks, cfg, dtype):
+    D, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope = cfg.head_dim - cfg.qk_rope_dim
+    return {
+        "wq": dense_init(ks[0], D, H * cfg.head_dim, dtype),
+        "wkv_a": dense_init(ks[1], D, r + cfg.qk_rope_dim, dtype),
+        "kv_norm": norm_init(r, "rmsnorm", dtype),
+        "wkv_b": dense_init(ks[2], r, H * (nope + cfg.v_dim), dtype),
+        "wo": dense_init(ks[3], H * cfg.v_dim, D, dtype),
+    }
+
+
+def _mla_project(p, x, cfg, positions):
+    """x: (B, S, D) -> q, k (B,S,H,head_dim), v (B,S,H,v_head_dim)."""
+    B, S, _ = x.shape
+    H, r, rope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_dim
+    nope = cfg.head_dim - rope
+    angles = rope_angles(positions, rope, cfg.rope_theta)
+    q = (x @ p["wq"]).reshape(B, S, H, cfg.head_dim)
+    kv_a = x @ p["wkv_a"]
+    c_kv = apply_norm(p["kv_norm"], kv_a[..., :r], "rmsnorm", cfg.norm_eps)
+    k_pe = apply_rope_interleaved(kv_a[..., r:].reshape(B, S, 1, rope), angles)
+    kv = (c_kv @ p["wkv_b"]).reshape(B, S, H, nope + cfg.v_dim)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope_interleaved(q[..., nope:], angles)], -1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, rope))], -1)
+    return q, k, kv[..., nope:]
 
 
 def _project(p, x, cfg, angles):
@@ -98,9 +139,10 @@ def _mask(qpos, kpos, window: Optional[int]):
 
 def row_block_attention(q, k, v, qpos, kpos, *, window: Optional[int],
                         q_chunk: int, scale: float):
-    """q: (B,Sq,H,hd), k/v: (B,Skv,Kv,hd), qpos: (Sq,), kpos: (Skv,)."""
+    """q: (B,Sq,H,hd), k: (B,Skv,Kv,hd), v: (B,Skv,Kv,vd), qpos: (Sq,),
+    kpos: (Skv,) -> (B,Sq,H,vd)."""
     B, Sq, H, hd = q.shape
-    Kv = k.shape[2]
+    Kv, vd = k.shape[2], v.shape[-1]
     G = H // Kv
     qg = q.reshape(B, Sq, Kv, G, hd)
 
@@ -122,8 +164,8 @@ def row_block_attention(q, k, v, qpos, kpos, *, window: Optional[int],
         qb = qg.reshape(B, nb, q_chunk, Kv, G, hd).transpose(1, 0, 2, 3, 4, 5)
         qpb = qpos.reshape(nb, q_chunk)
         _, outs = jax.lax.scan(lambda c, x: (c, block(*x)), None, (qb, qpb))
-        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, Kv, G, hd)
-    return out.reshape(B, Sq, H, hd)
+        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, Kv, G, vd)
+    return out.reshape(B, Sq, H, vd)
 
 
 # ---------------------------------------------------------------------------
@@ -141,7 +183,7 @@ def local_window_attention(q, k, v, qpos, kpos, *, window: int, scale: float):
 
     qg = q.reshape(B, nb, W, Kv, G, hd)
     kb = k.reshape(B, nb, W, Kv, hd)
-    vb = v.reshape(B, nb, W, Kv, hd)
+    vb = v.reshape(B, nb, W, Kv, v.shape[-1])
     # previous block (zeros + pos=-1 for block 0)
     prev = lambda x: jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
     k2 = jnp.concatenate([prev(kb), kb], axis=2)  # (B, nb, 2W, Kv, hd)
@@ -160,7 +202,7 @@ def local_window_attention(q, k, v, qpos, kpos, *, window: int, scale: float):
 
     out = jax.vmap(block, in_axes=(1, 1, 1, 0, 0), out_axes=1)(
         qg, k2, v2, qpb, kpb2)
-    return out.reshape(B, S, H, hd)
+    return out.reshape(B, S, H, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +220,7 @@ def decode_attention(q, cache_k, cache_v, cache_pos, qpos, *,
     keep = _mask(jnp.asarray(qpos)[None], cache_pos, window)  # (1, Sc)
     s = jnp.where(keep[None, None, None], s, NEG_INF)
     out = _gqa_out(jax.nn.softmax(s, axis=-1), cache_v)
-    return out.reshape(B, 1, H, hd)
+    return out.reshape(B, 1, H, cache_v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -190,7 +232,7 @@ def init_attn_cache(cfg, batch: int, max_len: int, dtype):
     Sc = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
     return {
         "k": jnp.zeros((batch, Sc, cfg.num_kv_heads, cfg.head_dim), dtype),
-        "v": jnp.zeros((batch, Sc, cfg.num_kv_heads, cfg.head_dim), dtype),
+        "v": jnp.zeros((batch, Sc, cfg.num_kv_heads, cfg.v_dim), dtype),
         "pos": jnp.full((Sc,), -1, jnp.int32),
     }
 
@@ -205,9 +247,12 @@ def apply_attention(p, x, cfg, positions, *, mode: str = "train",
     Returns (y, new_cache).
     """
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    angles = rope_angles(positions, cfg.head_dim, cfg.rope_theta,
-                         cfg.mrope_sections)
-    q, k, v = _project(p, x, cfg, angles)
+    if cfg.attn_kind == "mla":
+        q, k, v = _mla_project(p, x, cfg, positions)
+    else:
+        angles = rope_angles(positions, cfg.head_dim, cfg.rope_theta,
+                             cfg.mrope_sections)
+        q, k, v = _project(p, x, cfg, angles)
     B, S = x.shape[:2]
     # token positions along the sequence (1D; batch-uniform by construction)
     pos1d = positions[0, 0] if positions.ndim == 3 else positions[0]
@@ -249,7 +294,7 @@ def apply_attention(p, x, cfg, positions, *, mode: str = "train",
                 cpos = jnp.roll(pos1d[S - Sc:].astype(jnp.int32), shift, axis=0)
             new_cache = {"k": ck, "v": cv, "pos": cpos}
 
-    y = out.reshape(B, S, cfg.q_dim) @ p["wo"]
+    y = out.reshape(B, S, -1) @ p["wo"]
     if "wo_b" in p:
         y = y + row(p["wo_b"], y.ndim)
     return shard_residual(y), new_cache

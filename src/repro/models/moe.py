@@ -17,16 +17,29 @@ experts on a 16-wide axis), weights fall back to FSDP and the buffer shards
 its capacity dim over "model" instead.
 
 Overflow beyond capacity is dropped (Switch/GShard semantics, tested).
+
+``apply_dropless_moe`` is DeepSeek-V3's layer (arXiv:2412.19437 §2.1.2) on
+one expert-parallel share: the router scores all ``num_experts`` experts
+(sigmoid), picks the top-k by score plus a fixed correction bias, and
+weighs the picks by their scores renormalized and scaled; the layer holds
+experts [expert_offset, expert_offset + experts_held) and computes their
+part of the routed sum for every token routed to them, dropping none,
+plus the shared experts, which every share computes alike. The token-slots
+routed here are sorted by expert into a buffer sized for the worst case
+(every slot held) and the held experts run as grouped matmuls over it
+(``kernels.ops.grouped_matmul``); no exchange between shares is made.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init
+from repro.kernels.ops import grouped_matmul
+from repro.models.layers import apply_mlp, dense_init, mlp_init
 from repro.sharding import axis_size, shard, shard_residual
 
 
@@ -137,3 +150,125 @@ def apply_moe(p, x, cfg):
     y = jax.vmap(combine)(out_buf, dest, gate_w, keep)          # (G, Ng, D)
     y = y.reshape(B, S, D)
     return shard_residual(y), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless layer over the held share of the experts (DeepSeek-V3)
+# ---------------------------------------------------------------------------
+
+def dropless_init(key, cfg, dtype):
+    ks = jax.random.split(key, 5)
+    D, F, E, Eh = cfg.d_model, cfg.expert_d_ff, cfg.num_experts, \
+        cfg.held_experts
+    p = {"router": dense_init(ks[0], D, E, jnp.float32),
+         "router_bias": jnp.zeros((E,), jnp.float32),
+         "we_gate": _expert_init(ks[1], Eh, D, F, dtype),
+         "we_up": _expert_init(ks[2], Eh, D, F, dtype),
+         "we_down": _expert_init(ks[3], Eh, F, D, dtype)}
+    if cfg.num_shared_experts:
+        p["shared"] = mlp_init(ks[4], D, F * cfg.num_shared_experts,
+                               "swiglu", False, dtype)
+    return p
+
+
+def route(p, x, cfg):
+    """x (N, D) -> (weights (N, k) f32, experts (N, k) int32): sigmoid
+    scores in float32 over every expert, the top-k by score + correction
+    bias (a fixed parameter: no gradient reaches it), weighted by their
+    scores over the picks' sum, times ``routed_scaling``."""
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(p["router_bias"])
+    _, idx = jax.lax.top_k(scores + bias[None, :], cfg.experts_per_token)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling, idx
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _slots_by_expert(x, order, pos, k):
+    """Row r of the sorted buffer is token order[r] // k: x (N, D) ->
+    (N·k, D). The cotangent of each token sums its k slots, gathered by
+    ``pos`` (slot -> buffer row), with no scatter."""
+    return x[order // k]
+
+
+def _slots_fwd(x, order, pos, k):
+    return x[order // k], (order, pos)
+
+
+def _slots_bwd(k, res, g):
+    order, pos = res
+    n = pos.shape[0] // k
+    dx = jnp.sum(g[pos].reshape(n, k, -1).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+_slots_by_expert.defvjp(_slots_fwd, _slots_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, order, pos):
+    """Buffer rows back to slot order: y (N·k, D) -> y[pos]; the cotangent
+    is gathered back by ``order``."""
+    return y[pos]
+
+
+def _unsort_fwd(y, order, pos):
+    return y[pos], (order, pos)
+
+
+def _unsort_bwd(res, g):
+    order, _ = res
+    return g[order], None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def routed_experts(p, x, cfg):
+    """The held experts' part of the routed sum: x (N, D) -> (N, D).
+
+    Every (token, pick) slot whose expert is held goes to the front of an
+    (N·k, D) buffer, sorted by expert; the rows past the held slots are
+    masked on the way in (their gradient) and the slots not held on the way
+    out. The grouped matmuls' work follows the tokens routed here, and
+    nothing is dropped whatever the load."""
+    N, D = x.shape
+    k, Eh = cfg.experts_per_token, cfg.held_experts
+    with jax.named_scope("moe_route"):
+        w, idx = route(p, x, cfg)
+        local = idx - cfg.expert_offset
+        held = (local >= 0) & (local < Eh)
+        group = jnp.where(held, local, Eh).reshape(-1)        # (N·k,)
+        onehot = jax.nn.one_hot(group, Eh + 1, dtype=jnp.int32)
+        sizes = jnp.sum(onehot, axis=0)                       # (Eh+1,)
+        starts = jnp.cumsum(sizes) - sizes
+        rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot,
+                       axis=-1)
+        pos = starts[group] + rank        # slot -> buffer row (stable)
+        order = jnp.argsort(group, stable=True)   # buffer row -> slot
+        live = (jnp.arange(N * k) < jnp.sum(sizes[:Eh]))[:, None]
+    with jax.named_scope("moe_experts"):
+        gs = sizes[:Eh]
+        xs = jnp.where(live, _slots_by_expert(x, order, pos, k), 0)
+        h = jax.nn.silu(grouped_matmul(xs, p["we_gate"], gs)) \
+            * grouped_matmul(xs, p["we_up"], gs)
+        ys = grouped_matmul(h, p["we_down"], gs)
+        # masked before it is weighted: a product's gradient would carry
+        # the unwritten rows into the routing weights' gradient
+        ys = jnp.where(held[..., None],
+                       _unsort(ys, order, pos).reshape(N, k, D), 0)
+        y = jnp.sum(ys.astype(jnp.float32) * w[..., None], axis=1)
+    return y.astype(x.dtype)
+
+
+def apply_dropless_moe(p, x, cfg):
+    """x: (B, S, D) -> (y, aux = 0): the held experts' routed part plus the
+    shared experts (no auxiliary loss)."""
+    B, S, D = x.shape
+    y = routed_experts(p, x.reshape(B * S, D), cfg).reshape(B, S, D)
+    if "shared" in p:
+        y = y + apply_mlp(p["shared"], x, "swiglu")
+    return shard_residual(y), jnp.zeros((), jnp.float32)

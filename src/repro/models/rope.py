@@ -1,4 +1,5 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL style M-RoPE.
+"""Rotary position embeddings: standard RoPE (half-split and interleaved
+pairs) and Qwen2-VL style M-RoPE.
 
 M-RoPE (multimodal RoPE, arXiv:2409.12191) splits the head_dim/2 frequency
 bands into (temporal, height, width) sections; each section rotates by the
@@ -49,3 +50,15 @@ def apply_rope(x: jnp.ndarray, angles: jnp.ndarray) -> jnp.ndarray:
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def apply_rope_interleaved(x: jnp.ndarray, angles: jnp.ndarray) -> jnp.ndarray:
+    """Rotate the interleaved pairs (x[2i], x[2i+1]) by angles[..., i], as
+    DeepSeek-V3 rotates its decoupled q_pe / k_pe. x: (B, S, H, d);
+    angles: (B, S, d//2)."""
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     axis=-1).reshape(x.shape)

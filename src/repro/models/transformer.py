@@ -2,7 +2,9 @@
 
 A model is a repeated *period* of blocks (``cfg.layer_pattern``): pure dense
 archs have period ("attn",); jamba has an 8-block mamba/attention interleave;
-MoE FFNs replace dense FFNs on layers selected by (moe_period, moe_offset).
+MoE FFNs replace dense FFNs on layers selected by (moe_period, moe_offset),
+except on ``first_dense_layers`` leading layers (DeepSeek-V3's dense first
+layer), which are the client's whole stack when set.
 Weights for each position in the period are stacked over periods and the
 period is applied under ``lax.scan`` (+ per-period remat for training), so
 HLO size and compile time are independent of depth.
@@ -77,11 +79,13 @@ class TransformerLM:
         if cfg.vision_embed_dim:
             client["vision_proj"] = dense_init(k_vis, cfg.vision_embed_dim,
                                                cfg.d_model, dtype)
-        client["layers"] = self._init_stack(k_client, cfg.cut_periods, dtype)
+        client["layers"] = self._init_stack(k_client, cfg.cut_periods, dtype,
+                                            server=False)
 
         server: Params = {
             "layers": self._init_stack(
-                k_server, cfg.num_periods - cfg.cut_periods, dtype),
+                k_server, cfg.num_periods - cfg.cut_periods, dtype,
+                server=True),
             "final_norm": norm_init(cfg.d_model, cfg.norm_type, dtype),
         }
         if not cfg.tie_embeddings:
@@ -94,7 +98,8 @@ class TransformerLM:
                                             cfg.padded_vocab, dtype)
         return {"client": client, "server": server}
 
-    def _init_stack(self, key, n_periods: int, dtype) -> Params:
+    def _init_stack(self, key, n_periods: int, dtype, *, server: bool
+                    ) -> Params:
         cfg = self.cfg
 
         def init_period(k):
@@ -109,8 +114,10 @@ class TransformerLM:
                     lp["mixer"] = attn_mod.attn_init(kk[0], cfg, dtype)
                 else:
                     lp["mixer"] = ssm_mod.ssm_init(kk[0], cfg, dtype)
-                if self._pos_is_moe(pos):
-                    lp["ffn"] = moe_mod.moe_init(kk[1], cfg, dtype)
+                if self._pos_is_moe(pos, server):
+                    init = moe_mod.dropless_init \
+                        if cfg.moe_layer == "dropless" else moe_mod.moe_init
+                    lp["ffn"] = init(kk[1], cfg, dtype)
                 elif cfg.d_ff:
                     lp["ffn"] = mlp_init(kk[1], cfg.d_model, cfg.d_ff,
                                          cfg.mlp_type, cfg.use_bias, dtype)
@@ -123,10 +130,12 @@ class TransformerLM:
             return {}
         return jax.tree.map(lambda *xs: jnp.stack(xs), *periods)
 
-    def _pos_is_moe(self, pos: int) -> bool:
+    def _pos_is_moe(self, pos: int, server: bool) -> bool:
         # valid because period % moe_period == 0 and the cut offset is a whole
-        # number of periods, so the flag is position-static across the scan
+        # number of periods, so the flag is position-static across the scan;
+        # leading dense layers are the client's whole stack (ArchConfig)
         return bool(self.cfg.num_experts) and \
+            (server or not self.cfg.first_dense_layers) and \
             (pos % self.cfg.moe_period == self.cfg.moe_offset)
 
     # ----------------------------------------------------------- embeddings
@@ -148,7 +157,8 @@ class TransformerLM:
         return shard_residual(x)
 
     # ------------------------------------------------------------- periods
-    def _apply_period(self, pp: Params, x, positions, mode, caches, decode_pos):
+    def _apply_period(self, pp: Params, x, positions, mode, caches, decode_pos,
+                      server: bool):
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         new_caches = {} if caches is not None else None
@@ -180,11 +190,14 @@ class TransformerLM:
             y, new_c = maybe_ckpt(mixer_fn)(lp, x, cache)
             x = x + y
             if "ffn" in lp:
-                if self._pos_is_moe(pos):
+                if self._pos_is_moe(pos, server):
+                    apply = moe_mod.apply_dropless_moe \
+                        if cfg.moe_layer == "dropless" else moe_mod.apply_moe
+
                     def ffn_fn(lp_, x_):
                         h = apply_norm(lp_["ln2"], x_, cfg.norm_type,
                                        cfg.norm_eps)
-                        return moe_mod.apply_moe(lp_["ffn"], h, cfg)
+                        return apply(lp_["ffn"], h, cfg)
                     y, a = maybe_ckpt(ffn_fn)(lp, x)
                     aux = aux + a
                 else:
@@ -198,7 +211,8 @@ class TransformerLM:
                 new_caches[f"p{pos}"] = new_c
         return x, new_caches, aux
 
-    def _run_stack(self, layers: Params, x, positions, mode, caches, decode_pos):
+    def _run_stack(self, layers: Params, x, positions, mode, caches, decode_pos,
+                   server: bool):
         """Scan the stacked periods. caches: stacked pytree or None."""
         if not layers:
             return x, caches, jnp.zeros((), jnp.float32)
@@ -211,7 +225,7 @@ class TransformerLM:
             pslice, cslice = xs
             x, new_c, a = self._apply_period(pslice, x, positions, mode,
                                              cslice if has_caches else None,
-                                             decode_pos)
+                                             decode_pos, server)
             return (x, aux + a), (new_c if has_caches else cslice)
 
         if cfg.remat and mode == "train":
@@ -237,7 +251,8 @@ class TransformerLM:
         x = self.embed(client_params, batch)
         positions = self._positions(batch, x.shape[1], decode_pos)
         x, new_caches, aux = self._run_stack(client_params["layers"], x,
-                                             positions, mode, caches, decode_pos)
+                                             positions, mode, caches, decode_pos,
+                                             server=False)
         return x, new_caches, aux
 
     def _downlink(self) -> Optional[CutCompressor]:
@@ -352,7 +367,8 @@ class TransformerLM:
                        caches=None, decode_pos=None):
         positions = self._positions(batch, acts.shape[1], decode_pos)
         x, new_caches, aux = self._run_stack(server_params["layers"], acts,
-                                             positions, mode, caches, decode_pos)
+                                             positions, mode, caches, decode_pos,
+                                             server=True)
         x = apply_norm(server_params["final_norm"], x, self.cfg.norm_type,
                        self.cfg.norm_eps)
         return x, new_caches, aux

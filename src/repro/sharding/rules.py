@@ -41,7 +41,7 @@ _RULES = [
     (r"(^|/)head(_\d+)?$", {2: P("data", "model"), 3: P(None, "data", "model")}),
     (r"(^|/)vision_proj$", {2: P(None, "data")}),
     # attention ----------------------------------------------------------
-    (r"/(wq|wk|wv)$", {2: P("data", "model")}),
+    (r"/(wq|wk|wv|wkv_a|wkv_b)$", {2: P("data", "model")}),
     (r"/wo$", {2: P("model", "data")}),
     (r"/(wq_b|wk_b|wv_b)$", {1: P("model")}),
     (r"/wo_b$", {1: P("data")}),

@@ -75,6 +75,29 @@ def test_scalar_quantize_compiles_for_v5e(compiled):
     assert CUSTOM_CALL in c.as_text()
 
 
+# the held experts' grouped matmuls of the Moonlight-16B-A3B cell: 16,384
+# tokens x top-6 slots, 8 held experts; gate/up (2048 -> 1408), down back
+GMM_SHAPES = [(98304, 2048, 1408), (98304, 1408, 2048)]
+
+
+@pytest.mark.parametrize("m,k,n", GMM_SHAPES)
+def test_grouped_matmul_and_vjp_compile_for_v5e(compiled, m, k, n):
+    """Forward, the input's gradient and the weights' gradient: the three
+    Pallas kernels of one grouped product, each under its own name."""
+    bf16 = jnp.bfloat16
+
+    def fwd_and_vjp(lhs, rhs, sizes, ct):
+        out, vjp = jax.vjp(lambda a, b: ops.grouped_matmul(
+            a, b, sizes, backend="pallas"), lhs, rhs)
+        return out, vjp(ct)
+
+    c = compiled(fwd_and_vjp, ((m, k), bf16), ((8, k, n), bf16),
+                 ((8,), jnp.int32), ((m, n), bf16))
+    text = c.as_text()
+    assert text.count(CUSTOM_CALL) == 3
+    assert "moe_gmm_kernel" in text and "moe_tgmm_kernel" in text
+
+
 def test_femnist_stacked_step_compiles_for_v5e(one_chip, monkeypatch):
     """One whole FedLite step of the paper's FEMNIST task — PQ uplink and
     the topk+scalarq downlink both on their Pallas kernels — fits a chip."""

@@ -9,8 +9,9 @@ Every block a wrapper hands a kernel is legal for Mosaic: the PQ kernels
 take the points on lanes (``x.T``, see ``kmeans_assign.py``) in blocks of a
 multiple of 128 lanes; the scalar-quantize kernel takes (8k, 128m) tiles or
 the whole (padded) dimension; the grouped matmuls take row tiles of 512 (or
-all rows, padded to 8) and column tiles of 512 or the whole dimension. The
-public signatures stay row-major (N, D).
+all rows, padded to 8) and column tiles of 512 or the whole dimension; the
+flash attention kernels take sequence blocks of a multiple of 128 and the
+whole head width. The public signatures stay row-major (N, D).
 """
 
 from __future__ import annotations
@@ -20,6 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention import (block_tables, flash_attention_dkv,
+                                           flash_attention_dq,
+                                           flash_attention_fwd)
 from repro.kernels.kmeans_assign import kmeans_assign_kernel
 from repro.kernels.moe_gmm import (group_metadata, moe_gmm_kernel,
                                    moe_tgmm_kernel)
@@ -37,6 +41,8 @@ SCALARQ_COL_BLOCK = 1024
 GMM_ROW_BLOCK = 512
 GMM_COL_BLOCK = 512
 GMM_WHOLE_DIM = 2048
+# flash attention's query and key block along the sequence
+FLASH_BLOCK = 1024
 
 
 def _interpret_default() -> bool:
@@ -208,31 +214,74 @@ def assign_impl_for_kmeans(x: jax.Array, centroids: jax.Array) -> jax.Array:
     return codes
 
 
-@functools.partial(jax.jit, static_argnames=("num_q_heads", "num_kv_heads",
-                                             "scale", "window", "block_q",
-                                             "block_k", "interpret"))
-def flash_attention(q, k, v, *, num_q_heads: int, num_kv_heads: int,
-                    scale: float, window=None, block_q: int = 256,
-                    block_k: int = 256, interpret: bool | None = None):
-    """Padded wrapper for the flash kernel: accepts any S (pads to the block
-    multiple with masked tail — causal masking already zeroes the padding's
-    influence on real rows). Layout: q (B·H, S, hd), k/v (B·Kv, S, hd)."""
-    from repro.kernels.flash_attention import flash_attention as _fa
-    interpret = _interpret_default() if interpret is None else interpret
-    s = q.shape[1]
-    blk = max(block_q, block_k)
-    pad = (-s) % min(blk, max(s, 1))
-    if pad:
-        zq = jnp.zeros((q.shape[0], pad, q.shape[2]), q.dtype)
-        zk = jnp.zeros((k.shape[0], pad, k.shape[2]), k.dtype)
-        q = jnp.concatenate([q, zq], axis=1)
-        k = jnp.concatenate([k, zk], axis=1)
-        v = jnp.concatenate([v, zk], axis=1)
-    out = _fa(q, k, v, num_q_heads=num_q_heads, num_kv_heads=num_kv_heads,
-              scale=scale, window=window,
-              block_q=min(block_q, q.shape[1]),
-              block_k=min(block_k, q.shape[1]), interpret=interpret)
-    return out[:, :s]
+# ---------------------------------------------------------------------------
+# causal flash attention
+# ---------------------------------------------------------------------------
+
+def flash_block(seq_len: int) -> int | None:
+    """The flash kernels' sequence block for ``seq_len``: FLASH_BLOCK, or
+    the largest multiple of 128 below it that divides ``seq_len``; None
+    where no multiple of 128 does."""
+    b = min(FLASH_BLOCK, seq_len) // LANE * LANE
+    while b >= LANE and seq_len % b:
+        b -= LANE
+    return b if b >= LANE else None
+
+
+def _flash_fwd(q, k, v, qpos, kpos, scale, window, block, interpret):
+    kind, fetch_k, _ = block_tables(qpos, kpos, block, block, window)
+    o, lse = flash_attention_fwd(kind, fetch_k, q, k, v, qpos[:, None],
+                                 kpos[None, :], scale=scale, window=window,
+                                 block_q=block, block_k=block,
+                                 interpret=interpret)
+    return o, (q, k, v, qpos, kpos, o, lse)
+
+
+def _flash_bwd(scale, window, block, interpret, res, do):
+    q, k, v, qpos, kpos, o, lse = res
+    B, H, S, _ = q.shape
+    kind, fetch_k, fetch_q = block_tables(qpos, kpos, block, block, window)
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+    static = dict(scale=scale, window=window, block_q=block, block_k=block,
+                  interpret=interpret)
+    dq = flash_attention_dq(kind, fetch_k, q, k, v, do, lse, di,
+                            qpos[:, None], kpos[None, :], **static)
+    dk, dv = flash_attention_dkv(kind, fetch_q, q, k, v, do,
+                                 lse.reshape(B, H, 1, S),
+                                 di.reshape(B, H, 1, S), qpos[None, :],
+                                 kpos[:, None], **static)
+    return dq, dk, dv, None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, qpos, kpos, scale, window, block, interpret):
+    return _flash_fwd(q, k, v, qpos, kpos, scale, window, block,
+                      interpret)[0]
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     qpos: jax.Array, kpos: jax.Array, *, scale: float,
+                     window: int | None = None) -> jax.Array:
+    """Masked softmax attention through the flash kernels
+    (``kernels/flash_attention.py``), differentiable in q, k and v.
+
+    q (B, S, H, hd), k (B, S, Kv, hd), v (B, S, Kv, vd) with H a multiple
+    of Kv; qpos, kpos (S,) int positions. Keeps the pairs with qpos ≥ kpos,
+    kpos ≥ 0 and, with a window, qpos − kpos < window: the mask of
+    ``models/attention.row_block_attention``. S must have a ``flash_block``.
+    Returns (B, S, H, vd) in q's dtype."""
+    block = flash_block(q.shape[1])
+    if block is None:
+        raise ValueError(f"no flash block divides the sequence {q.shape[1]}")
+    heads = lambda x: x.transpose(0, 2, 1, 3)
+    o = _flash(heads(q), heads(k), heads(v), qpos.astype(jnp.int32),
+               kpos.astype(jnp.int32), scale, window, block,
+               _interpret_default())
+    return heads(o)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,12 @@
 """Grouped-query attention with RoPE/M-RoPE, and DeepSeek-V3's latent
-attention (MLA), over three execution paths:
+attention (MLA), over four execution paths:
 
+  * ``flash``: on a TPU, where the sequence tiles into the flash kernels'
+    blocks and no window shorter than the sequence cuts it, the fused
+    kernels of ``kernels/flash_attention.py`` (through
+    ``kernels/ops.causal_attention``): forward and backward keep every
+    score tile in VMEM and skip the tiles the mask drops whole. The same
+    mask and precision as ``row_block``, which is its fallback.
   * ``row_block``: causal (optionally windowed) attention computed in query
     row-blocks via ``lax.scan`` — peak memory O(q_chunk · S_kv) instead of
     O(S²). The block body is wrapped in ``jax.checkpoint`` so the backward
@@ -31,6 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import causal_attention, flash_block
 from repro.models.layers import apply_norm, dense_init, norm_init, row
 from repro.models.rope import apply_rope, apply_rope_interleaved, rope_angles
 from repro.sharding import shard, shard_residual
@@ -168,6 +175,15 @@ def row_block_attention(q, k, v, qpos, kpos, *, window: Optional[int],
     return out.reshape(B, Sq, H, vd)
 
 
+def use_flash(seq_len: int, window: Optional[int]) -> bool:
+    """Whether train and prefill attention over ``seq_len`` tokens take the
+    flash kernels: on a TPU, where the sequence tiles into their blocks and
+    the window, if any, spans it."""
+    return (jax.default_backend() == "tpu"
+            and flash_block(seq_len) is not None
+            and (window is None or window >= seq_len))
+
+
 # ---------------------------------------------------------------------------
 # path 2: exact block-local sliding-window attention
 # ---------------------------------------------------------------------------
@@ -273,6 +289,9 @@ def apply_attention(p, x, cfg, positions, *, mode: str = "train",
         if cfg.sliding_window and S > 2 * cfg.sliding_window and S % cfg.sliding_window == 0:
             out = local_window_attention(q, k, v, pos1d, pos1d,
                                          window=cfg.sliding_window, scale=scale)
+        elif use_flash(S, cfg.sliding_window):
+            out = causal_attention(q, k, v, pos1d, pos1d, scale=scale,
+                                   window=cfg.sliding_window)
         else:
             out = row_block_attention(q, k, v, pos1d, pos1d,
                                       window=cfg.sliding_window,

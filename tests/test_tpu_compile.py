@@ -130,3 +130,29 @@ def test_femnist_stacked_step_compiles_for_v5e(one_chip, monkeypatch):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES
+
+
+# the LM cells' attention: StarCoder2-3B (24 query heads over 2 key heads of
+# 128) at 2 x 4096, Moonlight's latent attention (16 heads, qk 192, v 128)
+# at 2 x 8192
+FLASH_SHAPES = [(2, 4096, 24, 2, 128, 128), (2, 8192, 16, 16, 192, 128)]
+
+
+@pytest.mark.parametrize("b,s,h,kv,hd,vd", FLASH_SHAPES)
+def test_flash_attention_and_vjp_compile_for_v5e(compiled, b, s, h, kv, hd,
+                                                 vd):
+    """The forward, dq and dkv kernels, each under its own name."""
+    bf16 = jnp.bfloat16
+
+    def fwd_and_vjp(q, k, v, ct):
+        pos = jnp.arange(s)
+        out, vjp = jax.vjp(lambda q, k, v: ops.causal_attention(
+            q, k, v, pos, pos, scale=hd ** -0.5), q, k, v)
+        return out, vjp(ct)
+
+    c = compiled(fwd_and_vjp, ((b, s, h, hd), bf16), ((b, s, kv, hd), bf16),
+                 ((b, s, kv, vd), bf16), ((b, s, h, vd), bf16))
+    text = c.as_text()
+    assert text.count(CUSTOM_CALL) == 3
+    assert all(n in text for n in ("flash_attention_fwd", "flash_attention_dq",
+                                   "flash_attention_dkv"))

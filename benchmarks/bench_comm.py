@@ -31,8 +31,8 @@ decoding bit-exactly.
 
 The ``pq_delta_downlink`` row closes the same loop for the OTHER
 direction: a pq downlink ships the cut-layer *gradient* as
-codebooks+codes, and until the stateful hook
-(``core/compressors.compress_downlink_stateful``) those codebooks were
+codebooks+codes, and until the ``downlink`` hook took codec state
+(``core/compressors.downlink``) those codebooks were
 fresh every round. A realistic gradient proxy (round-1 gradient = a small
 drift of round-0's) is quantized warm-started from round 0's
 `QuantizerState` and its codebook delta-encoded against the acked
@@ -182,7 +182,7 @@ def run(fast: bool = True):
     rows.append(measure_pq_delta(acts1, acts2,
                                  "pq_delta_measured_lmcut_d512_L16_b8"))
     # downlink: the gradient message of a pq downlink, steady state — the
-    # stateful-downlink (compress_downlink_stateful) analogue of the row
+    # stateful-downlink (``downlink`` hook with state) analogue of the row
     # above, at gradient scale
     g1 = 0.01 * jax.random.normal(jax.random.PRNGKey(4), (256, d_lm))
     g2 = g1 + 0.05 * 0.01 * jax.random.normal(jax.random.PRNGKey(5),

@@ -10,7 +10,7 @@ training step on the paper's FEMNIST CNN.
 import jax
 import jax.numpy as jnp
 
-from repro.core.correction import quantize_with_correction
+from repro.core.compressors import CutState, PQCompressor, uplink
 from repro.core.quantizer import PQConfig, quantize
 from repro.core.fedlite import TrainState, make_train_step
 from repro.data.synthetic import make_federated_image_data
@@ -31,7 +31,8 @@ def main():
 
     # --- 2. gradient correction (eq. 5) -------------------------------------
     lam = 1e-4
-    zt, vjp = jax.vjp(lambda x: quantize_with_correction(x, lam, pq), z)
+    zt, vjp = jax.vjp(
+        lambda x: uplink(x, lam, CutState(), PQCompressor(pq))[0], z)
     (g,) = vjp(jnp.ones_like(z))
     print(f"corrected cotangent == g + λ(z − z̃): "
           f"{bool(jnp.allclose(g, 1.0 + lam * (z - zt), atol=1e-6))}")

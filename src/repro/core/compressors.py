@@ -36,23 +36,20 @@ Every compressor answers three questions:
              in ``federated/wire.py`` (bit-exact round-trips, measured
              bytes validate the analytic model).
 
-Direction hooks (``jax.custom_vjp``):
+The cut boundary (one custom VJP per direction):
 
-  * ``compress_with_correction(_stats)`` — the uplink: forward emits the
-    reconstruction, backward adds FedLite's λ·(z − z̃) correction (eq. 5)
-    using the residual fused with the forward compress.
-  * ``compress_with_correction_carry`` — the state-carrying uplink: same
-    correction, but a `CutState` threads cross-round carry through the
-    round — PQ codebook warm-start (``compress_stateful`` /
-    `core/quantizer.QuantizerState`) and per-client `ErrorFeedback` memory
-    — returning ``(recon, distortion, new_state)``.
-  * ``compress_downlink`` — the downlink: forward is the identity, backward
-    passes the activation COTANGENT through the configured compressor
-    before it reaches the client submodel. ``none`` reproduces the
-    uncompressed backward pass bitwise (asserted in tests).
-  * ``compress_downlink_keyed`` — same, with a per-step PRNG key threaded
-    to the backward codec: ``scalarq`` downlinks round stochastically
-    (unbiased) instead of to-nearest.
+  * ``uplink`` — forward emits the reconstruction, backward adds FedLite's
+    λ·(z − z̃) correction (eq. 5) using the residual fused with the forward
+    compress. A `CutState` threads the cross-round carry: PQ codebook warm
+    start (``compress_stateful`` / `core/quantizer.QuantizerState`) and
+    error-feedback memory. Returns ``(recon, distortion, new_state)``.
+  * ``downlink`` — forward is the identity, backward passes the activation
+    COTANGENT through the codec before it reaches the client submodel,
+    with an optional PRNG key (stochastic ``scalarq`` rounding) and codec
+    state. ``none`` reproduces the uncompressed backward pass bitwise.
+
+``apply_cut`` applies both per client to client-major activations
+``(C, ..., d)``; every model calls it at its cut.
 
 Spec strings (``ArchConfig.uplink_compressor`` / ``downlink_compressor``,
 `FederatedTrainer` fields) are parsed by ``make_compressor``:
@@ -72,11 +69,10 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-import numpy as np
-
 from repro.core import kmeans as _km
 from repro.core.quantizer import (PQConfig, QuantizedBatch, QuantizerState,
                                   quantize, quantize_stateful)
+from repro.core.split import dtype_bits
 
 
 # ---------------------------------------------------------------------------
@@ -120,8 +116,8 @@ class CutState(NamedTuple):
         tensor: the accumulated compression error re-added to the next
         round's input (`ErrorFeedback` semantics, exact telescoping).
 
-    Passing a ``CutState`` (even one with both fields ``None``) to the
-    state-aware hooks requests a new state back — the bootstrap round.
+    The ``uplink`` hook returns the next round's state; from an empty
+    ``CutState()`` that is the bootstrap round.
     """
     quantizer: Any = None
     ef_memory: Any = None
@@ -212,7 +208,7 @@ class CutCompressor:
 
 @dataclasses.dataclass(frozen=True)
 class NoneCompressor(CutCompressor):
-    """Identity: dense payload, ``compress_downlink`` is a bitwise no-op."""
+    """Identity: dense payload; the ``downlink`` hook passes it bitwise."""
     name: str = dataclasses.field(default="none", init=False)
 
     def compress(self, z, *, key=None) -> Compressed:
@@ -326,8 +322,8 @@ class ScalarQuantCompressor(CutCompressor):
 
     ``codes = round((z − lo)/scale)`` with ``scale = (hi − lo)/(2^b − 1)``;
     stochastic rounding (unbiased, Caldas et al. 2018) when a PRNG ``key``
-    is passed to ``compress``, nearest rounding otherwise — the downlink
-    VJP hook runs keyless, hence deterministic. The quantize/dequantize hot
+    is passed to ``compress``, nearest rounding otherwise (the ``downlink``
+    hook passes the step's key when it has one). The quantize/dequantize hot
     loop runs through the same backend registry as the PQ encode: the
     Pallas kernel (``repro.kernels.scalar_quant``) on "pallas"/"auto"-on-TPU,
     pure jnp elsewhere."""
@@ -547,227 +543,162 @@ def make_compressor(spec, *, pq: Optional[PQConfig] = None
 
 
 # ---------------------------------------------------------------------------
-# direction hooks (custom VJPs)
+# the cut boundary: one hook per direction, one per-client cut
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def compress_with_correction(z: jax.Array, lam,
-                             compressor: CutCompressor) -> jax.Array:
-    """Uplink hook: forward emits the compressed reconstruction, backward
-    adds FedLite's λ·(z − z̃) correction (eq. 5) using the residual the
-    forward compress already produced. Generalizes
-    ``core/correction.quantize_with_correction`` to any registered codec."""
-    return compressor.compress(z).recon
-
-
-def _cwc_fwd(z, lam, compressor):
-    comp = compressor.compress(z)
-    return comp.recon, (comp.residual, jnp.asarray(lam, jnp.float32))
-
-
-def _cwc_bwd(compressor, res, g):
-    residual, lam = res
-    return (g + lam.astype(g.dtype) * residual.astype(g.dtype),
-            jnp.zeros_like(lam))
-
-
-compress_with_correction.defvjp(_cwc_fwd, _cwc_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def compress_with_correction_stats(z: jax.Array, lam,
-                                   compressor: CutCompressor):
-    """Like ``compress_with_correction`` but also returns the mean ‖z − z̃‖²
-    per vector as a second, non-differentiable output."""
-    comp = compressor.compress(z)
-    return comp.recon, _distortion(comp.residual)
-
-
 def _distortion(residual: jax.Array) -> jax.Array:
+    """Mean ‖z − z̃‖² per vector, from the residual."""
     r = residual.astype(jnp.float32)
     n = max(int(residual.size // residual.shape[-1]), 1)
     return jnp.sum(r * r) / n
 
 
-def _cwcs_fwd(z, lam, compressor):
-    comp = compressor.compress(z)
-    return ((comp.recon, _distortion(comp.residual)),
-            (comp.residual, jnp.asarray(lam, jnp.float32)))
-
-
-def _cwcs_bwd(compressor, res, g):
-    gz, _ = g   # the distortion output is a metric: its cotangent is dropped
-    residual, lam = res
-    return (gz + lam.astype(gz.dtype) * residual.astype(gz.dtype),
-            jnp.zeros_like(lam))
-
-
-compress_with_correction_stats.defvjp(_cwcs_fwd, _cwcs_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def compress_downlink(z: jax.Array, compressor: CutCompressor) -> jax.Array:
-    """Downlink hook: identity forward; the backward pass sends the
-    activation COTANGENT through ``compressor`` before it reaches the
-    client submodel — the server→client gradient message becomes a
-    compressed payload. With `NoneCompressor` the backward pass returns the
-    cotangent unchanged, bitwise-reproducing the uncompressed path
-    (asserted in tests/test_compressors.py)."""
-    return z
-
-
-def _dl_fwd(z, compressor):
-    return z, None
-
-
-def _dl_bwd(compressor, _, g):
-    if isinstance(compressor, NoneCompressor):
-        return (g,)
-    # scoped here, in the backward rule, so the scope lands on the codec's
-    # backward-pass operations
-    with jax.named_scope("fl_downlink_codec"):
-        return (compressor.compress(g).recon.astype(g.dtype),)
-
-
-compress_downlink.defvjp(_dl_fwd, _dl_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def compress_downlink_keyed(z: jax.Array, key: jax.Array,
-                            compressor: CutCompressor) -> jax.Array:
-    """``compress_downlink`` with a per-step PRNG key threaded into the
-    backward codec: ``scalarq`` (standalone or as a chain stage) then uses
-    *stochastic* rounding on the gradient cotangent — unbiased,
-    E[recon] = g (Caldas et al. 2018) — instead of round-to-nearest.
-
-    ``key`` is a raw uint32 PRNG key (``jax.random.PRNGKey`` /
-    ``fold_in``); its cotangent is the symbolic float0 zero. The keyless
-    ``compress_downlink`` remains the deterministic path and is
-    bitwise-unchanged."""
-    return z
-
-
-def _dlk_fwd(z, key, compressor):
-    return z, key
-
-
-def _dlk_bwd(compressor, key, g):
-    if isinstance(compressor, NoneCompressor):
-        gz = g
-    else:
-        with jax.named_scope("fl_downlink_codec"):
-            gz = compressor.compress(g, key=key).recon.astype(g.dtype)
-    # integer-dtype primals take float0 cotangents
-    return (gz, np.zeros(key.shape, jax.dtypes.float0))
-
-
-compress_downlink_keyed.defvjp(_dlk_fwd, _dlk_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def compress_downlink_stateful(z: jax.Array, state: Any,
-                               compressor: CutCompressor) -> jax.Array:
-    """``compress_downlink`` with cross-round codec state threaded IN.
-
-    ``state`` (e.g. a `core/quantizer.QuantizerState` from the previous
-    round, or ``None`` for a cold round) reaches the backward codec via
-    ``compressor.compress_stateful``: a ``pq`` downlink then warm-starts
-    Lloyd on the gradient cotangent from last round's gradient codebooks —
-    ``cfg.effective_warm_iters`` iterations instead of a cold
-    ``kmeans_iters`` recluster — exactly mirroring the uplink's
-    ``compress_with_correction_carry`` warm start. It is also what the
-    ``pq-delta`` wire kind diffs against, so the downlink codebook message
-    shrinks to b-bit deltas versus the acked reference
-    (``FederatedTrainer.codebook_delta_bits`` measures it;
-    ``bench_comm.py`` asserts the reduction).
-
-    The state is an auxiliary INPUT only — a VJP's backward pass cannot
-    emit new primal state, so the refreshed reference lineage is owned by
-    the measurement/trainer layer (the same split the uplink uses: warm
-    math in-jit, acked wire references host-side). ``state`` receives a
-    zero cotangent; ``None`` state runs the cold path, bitwise-identical
-    to ``compress_downlink``.
-    """
-    return z
-
-
-def _dls_fwd(z, state, compressor):
-    return z, state
-
-
-def _dls_bwd(compressor, state, g):
-    if isinstance(compressor, NoneCompressor):
-        gz = g
-    else:
-        with jax.named_scope("fl_downlink_codec"):
-            comp, _ = compressor.compress_stateful(g, state)
-            gz = comp.recon.astype(g.dtype)
-    return (gz, _zero_state_cotangent(state))
-
-
-compress_downlink_stateful.defvjp(_dls_fwd, _dls_bwd)
-
-
-# ---------------------------------------------------------------------------
-# the state-carrying uplink hook (warm-start + error feedback)
-# ---------------------------------------------------------------------------
-
-def _zero_state_cotangent(state):
-    """Cotangent pytree for a `CutState` primal: zeros for float leaves,
-    float0 for integer leaves (the round counter). The state is auxiliary
-    carry — no gradient may flow into last round's codebooks or memory."""
-    return jax.tree.map(
-        lambda x: np.zeros(jnp.shape(x), jax.dtypes.float0)
-        if jnp.issubdtype(jnp.asarray(x).dtype, jnp.integer)
-        else jnp.zeros_like(x), state)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def compress_with_correction_carry(z: jax.Array, lam, state: CutState,
-                                   compressor: CutCompressor):
-    """State-carrying uplink hook: like ``compress_with_correction_stats``
-    but threading a `CutState` across rounds. Returns
-    ``(recon, distortion, new_state)``.
-
-    Forward:
-      1. error feedback (iff ``state.ef_memory`` is not None):
-         ``z_in = z + memory`` — the accumulated compression error is
-         re-added before compressing (`ErrorFeedback` semantics); the new
-         memory is ``z_in − recon`` (== the compress residual), so the
-         telescoped sum of transmissions recovers the full signal.
-      2. warm-started compress: ``compressor.compress_stateful`` resumes
-         from ``state.quantizer`` (PQ codebook warm-start; stateless
-         codecs ignore it and return ``None``).
-
-    Backward: FedLite's eq.-5 correction ``g + λ·(z_in − recon)`` on the
-    activation cotangent, reusing the residual fused with the forward
-    compress; ``lam`` and the state get zero cotangents (the state is
-    auxiliary carry, not a differentiable input).
-    """
-    recon, dist, new_state, _ = _cwcarry(z, state, compressor)
-    return recon, dist, new_state
-
-
-def _cwcarry(z, state, compressor):
+def _uplink(z, state: CutState, compressor: CutCompressor):
     z_in = z if state.ef_memory is None \
         else z + state.ef_memory.astype(z.dtype)
     comp, new_q = compressor.compress_stateful(z_in, state.quantizer)
+    # PQ's distortion is accumulated in float32 before the residual's cast
+    dist = comp.payload.distortion \
+        if isinstance(comp.payload, QuantizedBatch) \
+        else _distortion(comp.residual)
     new_ef = None if state.ef_memory is None else comp.residual
-    new_state = CutState(quantizer=new_q, ef_memory=new_ef)
-    return comp.recon, _distortion(comp.residual), new_state, comp.residual
+    return comp.recon, dist, CutState(new_q, new_ef), comp.residual
 
 
-def _cwcarry_fwd(z, lam, state, compressor):
-    recon, dist, new_state, residual = _cwcarry(z, state, compressor)
-    return ((recon, dist, new_state),
-            (residual, jnp.asarray(lam, jnp.float32), state))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def uplink(z: jax.Array, lam, state: CutState, compressor: CutCompressor):
+    """The uplink hook: FedLite's gradient-corrected compression (§4.2).
+
+    Returns ``(recon, distortion, new_state)``. The codec is not
+    differentiable, and the server returns ∂h/∂z̃, the gradient at the
+    *compressed* activation. FedLite approximates the true ∂h/∂z with a
+    first-order correction that replaces the Hessian with λ·I:
+
+        g̃_z  =  ∂h/∂z̃  +  λ·(z − z̃)                               (eq. 5)
+
+    which, per Appendix A, is exactly the gradient of the surrogate loss
+    ‖z − ẑ‖² + (λ/2)‖z − z̃‖²: λ adds a regularizer that pulls the client
+    model toward activations with a low compression error. λ = 0 is the
+    straight-through estimator the paper ablates against. ``lam`` may be a
+    traced scalar, so a scheduled λ needs no recompile.
+
+    Forward: with ``state.ef_memory`` set, error feedback re-adds the
+    accumulated compression error first (``z_in = z + memory``; the new
+    memory is the residual ``z_in − recon``, `ErrorFeedback` semantics);
+    then ``compressor.compress_stateful`` resumes from ``state.quantizer``
+    (PQ codebook warm start; stateless codecs return ``None``). An empty
+    ``CutState()`` is the no-carry case, and its ``new_state`` bootstraps
+    the carry. ``distortion`` is the mean ‖z_in − z̃‖² per vector.
+
+    Backward: eq. 5 with the residual the forward compress produced (the
+    PQ encode emits it fused), so K-means runs once per forward and
+    backward. ``lam`` and ``state`` get zero cotangents: the state is
+    carry, not a differentiable input.
+    """
+    recon, dist, new_state, _ = _uplink(z, state, compressor)
+    return recon, dist, new_state
 
 
-def _cwcarry_bwd(compressor, res, g):
-    gz = g[0]   # distortion and state outputs are carry/metrics: dropped
-    residual, lam, state = res
+def _uplink_fwd(z, lam, state, compressor):
+    recon, dist, new_state, residual = _uplink(z, state, compressor)
+    return (recon, dist, new_state), (residual, jnp.asarray(lam, jnp.float32))
+
+
+def _uplink_bwd(compressor, res, g):
+    gz = g[0]   # the distortion and state outputs are metric and carry
+    residual, lam = res
     return (gz + lam.astype(gz.dtype) * residual.astype(gz.dtype),
-            jnp.zeros_like(lam), _zero_state_cotangent(state))
+            None, None)
 
 
-compress_with_correction_carry.defvjp(_cwcarry_fwd, _cwcarry_bwd)
+uplink.defvjp(_uplink_fwd, _uplink_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def downlink(z: jax.Array, key: Optional[jax.Array], state: Any,
+             compressor: CutCompressor) -> jax.Array:
+    """The downlink hook: identity forward; the backward pass sends the
+    activation COTANGENT through ``compressor`` before it reaches the
+    client submodel, so the server→client gradient message is a compressed
+    payload. With `NoneCompressor` the cotangent passes untouched, bitwise.
+
+    ``key`` (a raw uint32 PRNG key, or ``None``) reaches the codec:
+    ``scalarq``, alone or as a chain stage, then rounds stochastically
+    (unbiased, E[recon] = g; Caldas et al. 2018) instead of to nearest.
+    ``state`` (codec state such as a previous round's
+    `core/quantizer.QuantizerState`, or ``None`` for a cold round) reaches
+    ``compressor.compress_stateful``: a ``pq`` downlink then warm-starts
+    Lloyd on the gradient from last round's gradient codebooks. A backward
+    pass cannot emit new primal state, so the refreshed lineage stays with
+    the caller. Both get zero cotangents.
+    """
+    return z
+
+
+def _downlink_fwd(z, key, state, compressor):
+    return z, (key, state)
+
+
+def _downlink_bwd(compressor, res, g):
+    key, state = res
+    if not isinstance(compressor, NoneCompressor):
+        # scoped here, in the backward rule, so the scope lands on the
+        # codec's backward-pass operations
+        with jax.named_scope("fl_downlink_codec"):
+            comp, _ = compressor.compress_stateful(g, state, key=key)
+            g = comp.recon.astype(g.dtype)
+    return g, None, None
+
+
+downlink.defvjp(_downlink_fwd, _downlink_bwd)
+
+
+def _accounting_bits(compressor: CutCompressor, dtype) -> int:
+    """Float width φ a codec's message is counted at: PQ's own
+    ``phi_bits`` (the paper's fixed-width cost model), else the
+    activation dtype."""
+    if isinstance(compressor, PQCompressor):
+        return compressor.cfg.phi_bits
+    return dtype_bits(dtype)
+
+
+def apply_cut(z: jax.Array, up: Optional[CutCompressor],
+              down: Optional[CutCompressor], lam, *,
+              key: Optional[jax.Array] = None,
+              state: Optional[CutState] = None
+              ) -> Tuple[jax.Array, Dict[str, Any]]:
+    """The cut boundary, per client: ``z`` is client-major ``(C, ..., d)``.
+
+    Each client's slice goes through the `uplink` hook (``up``; ``None``
+    skips it) and then the `downlink` hook (``down``; ``None`` or
+    ``none`` skips it), vmapped over clients, so every client clusters
+    with its own codebooks. ``key`` is split into one key per client for
+    the downlink codec. ``state`` (a `CutState` whose leaves carry the
+    leading client axis) threads the uplink carry; the new state comes
+    back under ``stats["cut_state"]``.
+
+    Returns ``(z̃, stats)``. The stats count one client's message as the
+    wire carries it: ``pq_distortion`` (mean over clients),
+    ``pq_compression_ratio`` (dense bits over the uplink message's, per
+    client, at the codec's accounting width) and ``downlink_message_bits``
+    (all C messages). Each stat is one more output of the jitted step.
+    """
+    c, d = z.shape[0], z.shape[-1]
+    n = math.prod(z.shape[1:-1])   # vectors per client
+    stats: Dict[str, Any] = {}
+    if up is not None:
+        carry = CutState() if state is None else state
+        z, dist, new_state = jax.vmap(
+            lambda zi, si: uplink(zi, lam, si, up))(z, carry)
+        phi = _accounting_bits(up, z.dtype)
+        bits = up.analytic_bits(n, d, phi_bits=phi)
+        stats.update(pq_distortion=jnp.mean(dist),
+                     pq_compression_ratio=phi * n * d / max(bits, 1))
+        if state is not None:
+            stats["cut_state"] = new_state
+    if down is not None and not isinstance(down, NoneCompressor):
+        keys = None if key is None else jax.random.split(key, c)
+        z = jax.vmap(lambda zi, ki: downlink(zi, ki, None, down))(z, keys)
+        stats["downlink_message_bits"] = float(
+            c * down.analytic_bits(n, d, _accounting_bits(down, z.dtype)))
+    return z, stats

@@ -84,19 +84,12 @@ def make_train_step(model: TransformerLM, optimizer: Optimizer, *,
     treating metrics as scalars). Incompatible with ``microbatches > 1``.
     """
 
-    def loss_fn(params, batch, step, cut_state):
-        lam = None if lam_schedule is None else lam_schedule(step)
-        kw = {}
-        if step_key is not None:
-            kw["key"] = jax.random.fold_in(step_key, step)
-        if cut_state is not None:
-            kw["cut_state"] = cut_state
-        return model.loss(params, batch, quantize=quantize, lam_override=lam,
-                          **kw)
-
     def grads_of(params, batch, step, cut_state=None):
-        return jax.value_and_grad(loss_fn, has_aux=True)(params, batch, step,
-                                                         cut_state)
+        lam = None if lam_schedule is None else lam_schedule(step)
+        key = None if step_key is None else jax.random.fold_in(step_key, step)
+        return jax.value_and_grad(model.loss, has_aux=True)(
+            params, batch, quantize=quantize, lam_override=lam, key=key,
+            cut_state=cut_state)
 
     def train_step(state: TrainState, batch,
                    cut_state=None) -> Tuple[TrainState, Dict]:
@@ -165,14 +158,6 @@ def make_weighted_step(model, optimizer: Optimizer, *,
     mirror ``make_train_step``'s cut-layer threading, per client.
     """
 
-    def loss_fn(params, batch, key, cut_state):
-        kw = {}
-        if key is not None:
-            kw["key"] = key
-        if cut_state is not None:
-            kw["cut_state"] = cut_state
-        return model.loss(params, batch, quantize=quantize, **kw)
-
     def weighted_step(state: TrainState, batches, weights,
                       cut_state=None) -> Tuple[TrainState, Dict]:
         num_clients = weights.shape[0]
@@ -182,7 +167,8 @@ def make_weighted_step(model, optimizer: Optimizer, *,
 
         def per_client(params, b, key, cs):
             (loss, metrics), g = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, b, key, cs)
+                model.loss, has_aux=True)(params, b, quantize=quantize,
+                                          key=key, cut_state=cs)
             return g, loss, metrics
 
         grads, losses, metrics = jax.vmap(
@@ -267,14 +253,6 @@ def make_mesh_step(model, optimizer: Optimizer, mesh, *,
                          "'cohort' or 'client'")
     pre_scale = correction_scope == "cohort"
 
-    def loss_fn(params, batch, key, cut_state):
-        kw = {}
-        if key is not None:
-            kw["key"] = key
-        if cut_state is not None:
-            kw["cut_state"] = cut_state
-        return model.loss(params, batch, quantize=quantize, **kw)
-
     def mesh_step(state: TrainState, batches, weights, mask,
                   cut_state=None) -> Tuple[TrainState, Dict]:
         num_slots = weights.shape[0]
@@ -286,7 +264,8 @@ def make_mesh_step(model, optimizer: Optimizer, mesh, *,
         def shard_local(params, b, w, m, keys_l, cs):
             def per_client(b_i, s_i, key_i, cs_i):
                 def scaled(p):
-                    loss, metrics = loss_fn(p, b_i, key_i, cs_i)
+                    loss, metrics = model.loss(p, b_i, quantize=quantize,
+                                               key=key_i, cut_state=cs_i)
                     return loss * (s_i if pre_scale else 1.0), (loss, metrics)
 
                 (_, (loss, metrics)), g = jax.value_and_grad(

@@ -21,8 +21,8 @@ Where this sits in the compressor stack
 ---------------------------------------
 This module is the PQ *math*; it is one codec among several. The
 direction-agnostic registry in ``core/compressors.py`` wraps it as the
-``"pq"`` `CutCompressor` (the uplink default — what runs at the cut in
-`TransformerLM.cut_activation` and the paper models), next to ``none``,
+``"pq"`` `CutCompressor` (the uplink default — what ``apply_cut`` runs at
+every model's cut), next to ``none``,
 ``topk``, ``scalarq`` and ``chain`` (the downlink gradient codecs). Analytic
 bits come from ``PQConfig.message_bits`` here (the paper's §4.1 cost model,
 φdRL/q + Bq·log2 L); *measured* bits come from the tagged wire codec in
@@ -64,9 +64,9 @@ lloyd_update``) and the final encode (assignment + dequantize + residual):
     mode, which is for parity validation, not speed.
 
 The final encode is *fused*: one pass produces the dequantized activations
-z̃, the residual z − z̃ (consumed by the gradient-corrected VJP in
-``core/correction.py`` and ``core/compressors.compress_with_correction`` —
-it is NOT recomputed there), and the integer codes. On TPU this is one HBM
+z̃, the residual z − z̃ (consumed by the gradient-corrected VJP of
+``core/compressors.uplink`` — it is NOT recomputed there), and the integer
+codes. On TPU this is one HBM
 read + two writes per element instead of the three sweeps (assign, gather,
 subtract) of the naive path. Backends live in a registry
 (``repro.core.kmeans.register_backend``) so new substrates can be added

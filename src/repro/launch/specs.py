@@ -43,7 +43,7 @@ def make_model(cfg: ArchConfig, *, with_pq: bool = True,
                lam: float = 1e-4) -> TransformerLM:
     """Build the split LM with the arch's per-direction cut codecs.
 
-    ``cfg.uplink_compressor`` — "pq" keeps the paper's grouped PQ fast path
+    ``cfg.uplink_compressor`` — "pq" keeps the paper's grouped PQ
     (``with_pq=False`` or "none" disables it → SplitFed); any other spec is
     parsed by ``core/compressors.make_compressor``. ``cfg.downlink_compressor``
     installs a codec on the server→client gradient message ("none": the

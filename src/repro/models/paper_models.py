@@ -17,104 +17,49 @@ drives the paper models and the billion-parameter archs identically.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.compressors import (CutCompressor, CutState, PQCompressor,
-                                    compress_downlink,
-                                    compress_downlink_keyed,
-                                    compress_with_correction_carry)
-from repro.core.correction import quantize_with_correction_stats
+                                    apply_cut)
 from repro.core.quantizer import PQConfig
 from repro.models.layers import row
 
 Params = Dict[str, Any]
 
 
-def _maybe_quantize(x, pq: Optional[PQConfig], lam, quantize: bool,
-                    client_batch: int = 0, lam_override=None,
-                    downlink: Optional[CutCompressor] = None, *,
-                    key: Optional[jax.Array] = None,
-                    cut_state: Optional[CutState] = None):
-    """Apply the cut-layer codecs per client: the leading dim is split into
-    cohorts of ``client_batch`` examples, each clustered with its own
-    codebooks (vmap). client_batch=0 treats the whole batch as a single
-    client. ``downlink`` (a `CutCompressor`) squeezes the server→client
-    gradient cotangent inside the VJP; None/"none" leaves the backward
-    pass bitwise-untouched.
+def _cut(model, x, lam, key, cut_state):
+    """The cut codecs with the batch split into clients of
+    ``model.client_batch`` examples, each clustered with its own codebooks;
+    a batch that does not divide into clients (or client_batch=0) is one
+    client. ``cut_state`` follows the caller's layout: EF memory shaped like
+    ``x``, codebooks with a leading client axis under the split and without
+    one for a single client; the new state comes back the same way."""
+    b, cb = x.shape[0], model.client_batch
+    c = b // cb if cb and b % cb == 0 else 1
+    z = x.reshape(c, b // c, *x.shape[1:])
+    if cut_state is not None:
+        cut_state = _relayout(cut_state, z.shape,
+                              (lambda a: a) if c > 1 else (lambda a: a[None]))
+    up = None if model.pq is None else PQCompressor(model.pq)
+    z, stats = apply_cut(z, up, model.downlink_compressor, lam, key=key,
+                         state=cut_state)
+    if "cut_state" in stats:
+        stats["cut_state"] = _relayout(
+            stats["cut_state"], x.shape,
+            (lambda a: a) if c > 1 else (lambda a: a[0]))
+    return z.reshape(x.shape), stats
 
-    ``cut_state`` (a `CutState`, leaves with a leading client axis under
-    per-client splitting) switches the uplink to the state-carrying hook:
-    codebook warm-start + optional error feedback, with the updated state
-    returned under ``stats["cut_state"]``. ``key`` is a per-step PRNG key:
-    the downlink codec then uses stochastic rounding (scalarq). Both
-    default to ``None`` — the historical, bitwise-unchanged path."""
-    if lam_override is not None:
-        lam = lam_override
-    has_dl = quantize and downlink is not None and downlink.name != "none"
-    if not quantize or (pq is None and not has_dl):
-        return x, {}
-    per_client = bool(client_batch and x.shape[0] % client_batch == 0
-                      and x.shape[0] > client_batch)
-    stats = {}
-    zt = x
-    if pq is not None:
-        if cut_state is not None:
-            comp = PQCompressor(pq)
-            if per_client:
-                xs = x.reshape(x.shape[0] // client_batch, client_batch,
-                               *x.shape[1:])
-                # full-tensor EF memory follows the per-client split (and is
-                # flattened back below, so callers see the input layout)
-                if cut_state.ef_memory is not None and \
-                        cut_state.ef_memory.shape == x.shape:
-                    cut_state = cut_state._replace(
-                        ef_memory=cut_state.ef_memory.reshape(xs.shape))
-                zt, dist, new_state = jax.vmap(
-                    lambda zi, si: compress_with_correction_carry(
-                        zi, lam, si, comp))(xs, cut_state)
-                zt, dist = zt.reshape(x.shape), jnp.mean(dist)
-                if new_state.ef_memory is not None:
-                    new_state = new_state._replace(
-                        ef_memory=new_state.ef_memory.reshape(x.shape))
-            else:
-                zt, dist, new_state = compress_with_correction_carry(
-                    x, lam, cut_state, comp)
-            stats["cut_state"] = new_state
-        elif per_client:
-            xs = x.reshape(x.shape[0] // client_batch, client_batch,
-                           *x.shape[1:])
-            zt, dist = jax.vmap(
-                lambda zi: quantize_with_correction_stats(zi, lam, pq))(xs)
-            zt, dist = zt.reshape(x.shape), jnp.mean(dist)
-        else:
-            zt, dist = quantize_with_correction_stats(x, lam, pq)
-        n = x.size // x.shape[-1]
-        stats.update({
-            "pq_distortion": dist,
-            "pq_compression_ratio": float(
-                pq.compression_ratio(int(n), x.shape[-1])),
-        })
-    if has_dl:
-        if per_client:
-            zs = zt.reshape(zt.shape[0] // client_batch, client_batch,
-                            *zt.shape[1:])
-            if key is None:
-                zs = jax.vmap(
-                    lambda zi: compress_downlink(zi, downlink))(zs)
-            else:
-                dkeys = jax.random.split(key, zs.shape[0])
-                zs = jax.vmap(
-                    lambda zi, ki: compress_downlink_keyed(
-                        zi, ki, downlink))(zs, dkeys)
-            zt = zs.reshape(zt.shape)
-        elif key is None:
-            zt = compress_downlink(zt, downlink)
-        else:
-            zt = compress_downlink_keyed(zt, key, downlink)
-    return zt, stats
+
+def _relayout(state: CutState, ef_shape, lead) -> CutState:
+    """``state`` with its EF memory reshaped to ``ef_shape`` and ``lead``
+    applied to each codebook-state leaf."""
+    return CutState(
+        jax.tree.map(lead, state.quantizer),
+        None if state.ef_memory is None
+        else state.ef_memory.reshape(ef_shape))
 
 
 def _split_loss(model, params: Params, batch, head, quantize: bool,
@@ -125,11 +70,11 @@ def _split_loss(model, params: Params, batch, head, quantize: bool,
     one layer of the server update. Returns (loss, codec stats)."""
     with jax.named_scope("fl_client"):
         acts = model.client_forward(params["client"], batch)
-    with jax.named_scope("fl_uplink_codec"):
-        acts, stats = _maybe_quantize(acts, model.pq, model.lam, quantize,
-                                       model.client_batch, lam_override,
-                                       model.downlink_compressor,
-                                       key=key, cut_state=cut_state)
+    stats = {}
+    if quantize:
+        lam = model.lam if lam_override is None else lam_override
+        with jax.named_scope("fl_uplink_codec"):
+            acts, stats = _cut(model, acts, lam, key, cut_state)
     with jax.named_scope("fl_server"):
         loss = head(model.server_logits(params["server"], acts), batch)
     return loss, stats

@@ -24,7 +24,6 @@ multi-codebook token grids; this module implements only the decoder backbone.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -32,13 +31,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.compressors import (CutCompressor, CutState, PQCompressor,
-                                    compress_downlink,
-                                    compress_downlink_keyed,
-                                    compress_with_correction_carry,
-                                    compress_with_correction_stats)
-from repro.core.correction import quantize_with_correction_stats
+                                    apply_cut)
 from repro.core.quantizer import PQConfig
-from repro.core.split import dtype_bits
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -54,10 +48,8 @@ class TransformerLM:
     cfg: ArchConfig
     pq: Optional[PQConfig] = None     # FedLite quantizer at the cut layer
     lam: float = 0.0                  # gradient-correction strength (eq. 5)
-    downlink_pq: Optional[PQConfig] = None  # legacy: PQ on the downlink
-    #                                   (subsumed by downlink_compressor)
     # direction-agnostic cut-layer codecs (core/compressors.py):
-    # uplink_compressor replaces the PQ fast path when set; the downlink
+    # uplink_compressor replaces PQCompressor(pq) when set; the downlink
     # compressor squeezes the server->client gradient COTANGENT in the VJP
     uplink_compressor: Optional[CutCompressor] = None
     downlink_compressor: Optional[CutCompressor] = None
@@ -255,113 +247,27 @@ class TransformerLM:
                                              server=False)
         return x, new_caches, aux
 
-    def _downlink(self) -> Optional[CutCompressor]:
-        if self.downlink_compressor is not None:
-            return self.downlink_compressor
-        if self.downlink_pq is not None:       # legacy PQConfig field
-            return PQCompressor(self.downlink_pq)
-        return None
-
-    def cut_activation(self, x: jax.Array, *, quantize: bool,
-                       lam_override=None, key: Optional[jax.Array] = None,
-                       cut_state: Optional[CutState] = None
-                       ) -> Tuple[jax.Array, Dict]:
-        """Apply the cut-layer codecs (paper Fig. 1 generalized) at the cut.
-
-        Each batch row (sequence) is one *client*: codebooks are built
-        per-row (vmap), matching the paper's per-client, per-iteration
-        clustering — and making the compression step embarrassingly parallel
-        over the batch-sharded mesh axis (zero added collectives).
-
-        Uplink: ``pq`` (the paper's grouped PQ with the corrected VJP — the
-        exact pre-refactor path) unless ``uplink_compressor`` overrides it.
-        Downlink: ``downlink_compressor`` squeezes the activation COTANGENT
-        inside the VJP before it reaches the client stack; ``None``/"none"
-        leaves the backward pass untouched bitwise.
-
-        ``cut_state`` (leaves carrying a leading batch/client axis) routes
-        the uplink through the state-carrying hook — cross-round codebook
-        warm-start + optional error feedback — and the updated state comes
-        back under ``stats["cut_state"]``. ``key`` makes the downlink codec
-        round stochastically. Both default to ``None``: the historical
-        bitwise-identical path.
-        """
-        up = self.uplink_compressor
-        dl = self._downlink()
-        has_up = quantize and (up is not None or self.pq is not None)
-        has_dl = quantize and dl is not None and dl.name != "none"
-        if not has_up and not has_dl:
+    def _cut(self, x: jax.Array, *, quantize: bool, lam_override=None,
+             key: Optional[jax.Array] = None,
+             cut_state: Optional[CutState] = None
+             ) -> Tuple[jax.Array, Dict]:
+        """The cut-layer codecs (``core/compressors.apply_cut``), one client
+        per batch row (sequence): codebooks are built per row, matching the
+        paper's per-client clustering, and the codecs need no collective
+        over the batch-sharded mesh axis. ``cut_state`` leaves carry the
+        leading row axis."""
+        if not quantize:
             return x, {}
-        # gather each client's (sequence-sharded) activation so the per-client
-        # compression runs locally — exactly what a real client does, and it
-        # keeps the codecs free of collectives
-        x = shard(x, ("pod", "data"), None, None)
+        up = self.uplink_compressor
+        if up is None and self.pq is not None:
+            up = PQCompressor(self.pq)
         lam = self.lam if lam_override is None else lam_override
-        n_per_client = int(x.shape[1])  # tokens per client (= sequence)
-        phi = dtype_bits(getattr(self.cfg, "dtype", "float32"))
-        z_tilde, stats = x, {}
-        if has_up and cut_state is not None:
-            comp = up if up is not None else PQCompressor(self.pq)
-            z_tilde, dist, new_state = jax.vmap(
-                lambda zi, si: compress_with_correction_carry(
-                    zi, lam, si, comp))(x, cut_state)
-            stats = {"pq_distortion": jnp.mean(dist),
-                     "cut_state": new_state}
-            # same wire accounting the stateless branches emit, so metrics
-            # consumers see identical keys with the carry on or off
-            if up is None:
-                stats.update({
-                    "pq_message_bits": float(
-                        x.shape[0] * self.pq.message_bits(n_per_client,
-                                                          x.shape[-1])),
-                    "pq_compression_ratio": float(
-                        self.pq.compression_ratio(n_per_client,
-                                                  x.shape[-1])),
-                })
-            else:
-                msg = up.analytic_bits(n_per_client, x.shape[-1],
-                                       phi_bits=phi)
-                stats.update({
-                    "uplink_message_bits": float(x.shape[0] * msg),
-                    "uplink_compression_ratio":
-                        phi * n_per_client * x.shape[-1] / max(msg, 1),
-                })
-        elif has_up and up is None:
-            # the PQ fast path: fused backend encode + residual reuse
-            z_tilde, dist = jax.vmap(
-                lambda zi: quantize_with_correction_stats(zi, lam, self.pq))(x)
-            stats = {
-                "pq_distortion": jnp.mean(dist),
-                "pq_message_bits": float(
-                    x.shape[0] * self.pq.message_bits(n_per_client,
-                                                      x.shape[-1])),
-                "pq_compression_ratio": float(
-                    self.pq.compression_ratio(n_per_client, x.shape[-1])),
-            }
-        elif has_up:
-            z_tilde, dist = jax.vmap(
-                lambda zi: compress_with_correction_stats(zi, lam, up))(x)
-            msg = up.analytic_bits(n_per_client, x.shape[-1], phi_bits=phi)
-            stats = {
-                "pq_distortion": jnp.mean(dist),
-                "uplink_message_bits": float(x.shape[0] * msg),
-                "uplink_compression_ratio":
-                    phi * n_per_client * x.shape[-1] / max(msg, 1),
-            }
-        if has_dl:
-            if key is None:
-                z_tilde = jax.vmap(
-                    lambda zi: compress_downlink(zi, dl))(z_tilde)
-            else:
-                dkeys = jax.random.split(key, z_tilde.shape[0])
-                z_tilde = jax.vmap(
-                    lambda zi, ki: compress_downlink_keyed(
-                        zi, ki, dl))(z_tilde, dkeys)
-            stats["downlink_message_bits"] = float(
-                x.shape[0] * dl.analytic_bits(n_per_client, x.shape[-1],
-                                              phi_bits=phi))
-        z_tilde = shard_residual(z_tilde)
-        return z_tilde, stats
+        # gather each client's (sequence-sharded) activation so the
+        # per-client compression runs locally, as a real client's does
+        x = shard(x, ("pod", "data"), None, None)
+        z, stats = apply_cut(x, up, self.downlink_compressor, lam, key=key,
+                             state=cut_state)
+        return shard_residual(z), stats
 
     def server_forward(self, server_params: Params, acts, batch, *, mode="train",
                        caches=None, decode_pos=None):
@@ -407,9 +313,9 @@ class TransformerLM:
             acts, _, aux_c = self.client_forward(params["client"], batch,
                                                  mode="train")
         with jax.named_scope("fl_uplink_codec"):
-            acts, pq_stats = self.cut_activation(acts, quantize=quantize,
-                                                 lam_override=lam_override,
-                                                 key=key, cut_state=cut_state)
+            acts, pq_stats = self._cut(acts, quantize=quantize,
+                                       lam_override=lam_override, key=key,
+                                       cut_state=cut_state)
         with jax.named_scope("fl_server"):
             x, _, aux_s = self.server_forward(params["server"], acts, batch,
                                               mode="train")
@@ -491,7 +397,7 @@ class TransformerLM:
         """
         acts, c_caches, _ = self.client_forward(
             params["client"], batch, mode="prefill", caches=caches["client"])
-        acts, _ = self.cut_activation(acts, quantize=quantize)
+        acts, _ = self._cut(acts, quantize=quantize)
         x, s_caches, _ = self.server_forward(
             params["server"], acts, batch, mode="prefill", caches=caches["server"])
         lg = self.logits(params, x[:, -1:])

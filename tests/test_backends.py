@@ -2,7 +2,7 @@
 
 jnp vs pallas(-interpret) parity on codes / z̃ / residual, VJP parity under
 the gradient correction, "auto" resolution, and the single-K-means-run
-invariant of ``quantize_with_correction``.
+invariant of the PQ uplink hook.
 """
 
 import dataclasses
@@ -13,8 +13,13 @@ import numpy as np
 import pytest
 
 from repro.core import kmeans as km
-from repro.core.correction import quantize_with_correction
+from repro.core.compressors import CutState, PQCompressor, uplink
 from repro.core.quantizer import PQConfig, quantize
+
+
+def pq_uplink(z, lam, cfg):
+    """The PQ uplink hook's reconstruction, no carry."""
+    return uplink(z, lam, CutState(), PQCompressor(cfg))[0]
 
 
 def _cfg(backend, **kw):
@@ -40,14 +45,14 @@ def test_jnp_pallas_parity_codes_zt_residual(n, r):
 
 
 def test_vjp_parity_between_backends():
-    """quantize_with_correction's VJP under pallas == jnp to fp32 tolerance."""
+    """The PQ uplink hook's VJP under pallas == jnp to fp32 tolerance."""
     z = jax.random.normal(jax.random.PRNGKey(3), (48, 16))
     g_in = jax.random.normal(jax.random.PRNGKey(4), (48, 16))
     lam = 0.37
     outs = {}
     for backend in ("jnp", "pallas"):
         zt, vjp = jax.vjp(
-            lambda x: quantize_with_correction(x, lam, _cfg(backend)), z)
+            lambda x: pq_uplink(x, lam, _cfg(backend)), z)
         (g_out,) = vjp(g_in)
         outs[backend] = (zt, g_out)
         # eq. (5) must hold within each backend too
@@ -96,7 +101,7 @@ def test_register_custom_backend():
 
 
 def test_correction_runs_kmeans_exactly_once(monkeypatch):
-    """Forward+backward of quantize_with_correction traces K-means ONCE:
+    """Forward+backward of the PQ uplink hook traces K-means ONCE:
     the residual is emitted by the fused encode and reused by the VJP."""
     calls = {"lloyd": 0, "encode": 0}
     real_lloyd = km.lloyd
@@ -121,7 +126,7 @@ def test_correction_runs_kmeans_exactly_once(monkeypatch):
     z = jax.random.normal(jax.random.PRNGKey(7), (32, 16))
     cfg = _cfg("jnp")
     out, grad = jax.value_and_grad(
-        lambda x: jnp.sum(quantize_with_correction(x, 0.5, cfg) ** 2))(z)
+        lambda x: jnp.sum(pq_uplink(x, 0.5, cfg) ** 2))(z)
     assert np.isfinite(float(out)) and np.isfinite(np.asarray(grad)).all()
     # one vmapped Lloyd + one vmapped encode across fwd AND bwd (R=1 group)
     assert calls["lloyd"] == 1

@@ -235,7 +235,7 @@ def test_downlink_none_is_bitwise_identity():
     z = _z((6, 32))
 
     def f_hooked(x):
-        return jnp.sum(jnp.sin(C.compress_downlink(x, cn)) ** 2)
+        return jnp.sum(jnp.sin(C.downlink(x, None, None, cn)) ** 2)
 
     def f_plain(x):
         return jnp.sum(jnp.sin(x) ** 2)
@@ -249,7 +249,7 @@ def test_downlink_compresses_cotangent_only():
     """Forward values are untouched; the backward cotangent is sparsified."""
     c = C.TopKCompressor(k=0.1)
     z = _z((4, 64))
-    out, vjp = jax.vjp(lambda x: C.compress_downlink(x, c), z)
+    out, vjp = jax.vjp(lambda x: C.downlink(x, None, None, c), z)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(z))
     g = jax.random.normal(jax.random.PRNGKey(1), z.shape)
     (gz,) = vjp(g)
@@ -261,22 +261,90 @@ def test_downlink_compresses_cotangent_only():
                                rtol=1e-6)
 
 
-def test_compress_with_correction_matches_pq_path():
-    """The generic uplink hook over PQCompressor == the specialized
-    quantize_with_correction (same fused residual, same λ-corrected VJP)."""
-    from repro.core.correction import quantize_with_correction
+def test_uplink_hook_matches_pq_path():
+    """The uplink hook over PQCompressor is the grouped PQ itself: forward
+    == ``quantize(z).dequantized``, distortion == the quantizer's own, and
+    the cotangent == g + λ·residual (eq. 5, the fused encode's residual)."""
+    from repro.core.quantizer import quantize
     z = _z((10, 64), seed=6)
-    pqc = C.PQCompressor(cfg=PQ)
-
-    def loss_generic(x):
-        return jnp.sum(C.compress_with_correction(x, 0.3, pqc) ** 2)
-
-    def loss_pq(x):
-        return jnp.sum(quantize_with_correction(x, 0.3, PQ) ** 2)
-
-    np.testing.assert_allclose(np.asarray(jax.grad(loss_generic)(z)),
-                               np.asarray(jax.grad(loss_pq)(z)),
+    g = _z((10, 64), seed=7)
+    lam = 0.3
+    qb = quantize(z, PQ)
+    (recon, dist), vjp = jax.vjp(
+        lambda x: C.uplink(x, lam, C.CutState(),
+                           C.PQCompressor(cfg=PQ))[:2], z)
+    np.testing.assert_array_equal(np.asarray(recon),
+                                  np.asarray(qb.dequantized))
+    np.testing.assert_array_equal(np.asarray(dist),
+                                  np.asarray(qb.distortion))
+    (gz,) = vjp((g, jnp.zeros(())))
+    np.testing.assert_allclose(np.asarray(gz),
+                               np.asarray(g + lam * qb.residual),
                                rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("case", ["femnist_split", "femnist_one_client",
+                                  "lm_rows"])
+def test_cut_is_the_hooks_per_client(case):
+    """The models' shared cut (``apply_cut``) gives each client what the two
+    hooks give on that client alone: forward, cotangent (with the step key
+    split per client for a stochastic downlink) and stats. FEMNIST with
+    client_batch 4 splits 8 examples into 2 clients and keeps 6 as one
+    client; the LM makes each sequence a client."""
+    from repro.models import paper_models
+    lam, key = 0.3, jax.random.PRNGKey(5)
+    down = C.make_compressor("scalarq(bits=4)")
+    if case == "lm_rows":
+        from repro.configs.base import get_arch
+        from repro.models.transformer import TransformerLM
+        cfg = get_arch("llama3_8b", smoke=True)
+        pq = PQConfig(num_subvectors=cfg.d_model // 8, num_clusters=4,
+                      kmeans_iters=2)
+        model = TransformerLM(cfg, pq=pq, lam=lam, downlink_compressor=down)
+        x = _z((2, 16, cfg.d_model), seed=11)
+        clients = [x[0], x[1]]
+
+        def cut(v):
+            return model._cut(v, quantize=True, key=key)
+    else:
+        model = paper_models.FemnistCNN(pq=PQ, lam=lam, client_batch=4,
+                                        downlink_compressor=down)
+        x = _z((8 if case == "femnist_split" else 6, 64), seed=11)
+        clients = [x[:4], x[4:]] if case == "femnist_split" else [x]
+
+        def cut(v):
+            return paper_models._cut(model, v, lam, key, None)
+
+    g = _z(x.shape, seed=12)
+    zt, vjp, stats = jax.vjp(cut, x, has_aux=True)
+    (gx,) = vjp(g)
+    up = C.PQCompressor(model.pq)
+    keys = jax.random.split(key, len(clients))
+    dists, outs, grads = [], [], []
+    for zi, ki, gi in zip(clients, keys, np.split(np.asarray(g),
+                                                  len(clients))):
+        def hooks(v):
+            r, dist, _ = C.uplink(v, lam, C.CutState(), up)
+            return C.downlink(r, ki, None, down), dist
+
+        (out, dist), hvjp = jax.vjp(hooks, zi)
+        dists.append(float(dist))
+        outs.append(np.asarray(out))
+        grads.append(np.asarray(hvjp((gi.reshape(zi.shape),
+                                      jnp.zeros(())))[0]))
+    np.testing.assert_allclose(np.asarray(zt).reshape(-1),
+                               np.concatenate(outs, None), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(gx).reshape(-1),
+                               np.concatenate(grads, None), rtol=1e-5,
+                               atol=1e-6)
+    n = clients[0].size // x.shape[-1]
+    np.testing.assert_allclose(float(stats["pq_distortion"]),
+                               np.mean(dists), rtol=1e-5)
+    assert stats["pq_compression_ratio"] == \
+        model.pq.compression_ratio(n, x.shape[-1])
+    assert stats["downlink_message_bits"] == len(clients) * \
+        down.analytic_bits(n, x.shape[-1], 32)
 
 
 def test_model_downlink_none_bitwise_grads():

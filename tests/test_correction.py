@@ -5,16 +5,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.correction import quantize_with_correction
+from repro.core.compressors import CutState, PQCompressor, downlink, uplink
 from repro.core.quantizer import PQConfig, quantize
 
 
 CFG = PQConfig(num_subvectors=4, num_clusters=4, kmeans_iters=8)
 
 
+def pq_uplink(z, lam, cfg):
+    """The PQ uplink hook's reconstruction, no carry."""
+    return uplink(z, lam, CutState(), PQCompressor(cfg))[0]
+
+
 def test_forward_equals_plain_quantize():
     z = jax.random.normal(jax.random.PRNGKey(0), (32, 16))
-    zt = quantize_with_correction(z, 0.1, CFG)
+    zt = pq_uplink(z, 0.1, CFG)
     np.testing.assert_allclose(zt, quantize(z, CFG).dequantized, rtol=1e-6)
 
 
@@ -23,7 +28,7 @@ def test_vjp_is_eq5(lam):
     """cotangent(z) == g + λ(z − z̃) exactly (paper eq. 5)."""
     z = jax.random.normal(jax.random.PRNGKey(1), (16, 16))
     g_in = jax.random.normal(jax.random.PRNGKey(2), (16, 16))
-    zt, vjp = jax.vjp(lambda x: quantize_with_correction(x, lam, CFG), z)
+    zt, vjp = jax.vjp(lambda x: pq_uplink(x, lam, CFG), z)
     (g_out,) = vjp(g_in)
     expected = g_in + lam * (z - zt)
     np.testing.assert_allclose(g_out, expected, rtol=1e-5, atol=1e-6)
@@ -44,7 +49,7 @@ def test_surrogate_loss_equivalence():
 
     grad_s = jax.grad(surrogate)(z)
     # eq. (5) cotangent with incoming g
-    _, vjp = jax.vjp(lambda x: quantize_with_correction(x, lam, CFG), z)
+    _, vjp = jax.vjp(lambda x: pq_uplink(x, lam, CFG), z)
     (g_corrected,) = vjp(g)
     np.testing.assert_allclose(grad_s, g_corrected, rtol=1e-4, atol=1e-5)
 
@@ -52,7 +57,7 @@ def test_surrogate_loss_equivalence():
 def test_lambda_zero_is_straight_through():
     z = jax.random.normal(jax.random.PRNGKey(5), (16, 16))
     g = jnp.ones_like(z)
-    _, vjp = jax.vjp(lambda x: quantize_with_correction(x, 0.0, CFG), z)
+    _, vjp = jax.vjp(lambda x: pq_uplink(x, 0.0, CFG), z)
     (g_out,) = vjp(g)
     np.testing.assert_allclose(g_out, g)
 
@@ -64,7 +69,7 @@ def test_correction_pulls_toward_lower_quantization_error():
     lam = 1.0
     zt = quantize(z, CFG).dequantized
     err0 = float(jnp.mean(jnp.sum((z - zt) ** 2, -1)))
-    _, vjp = jax.vjp(lambda x: quantize_with_correction(x, lam, CFG), z)
+    _, vjp = jax.vjp(lambda x: pq_uplink(x, lam, CFG), z)
     (g,) = vjp(jnp.zeros_like(z))       # pure correction term
     z2 = z - 0.5 * g
     zt2 = quantize(z2, CFG).dequantized
@@ -74,11 +79,10 @@ def test_correction_pulls_toward_lower_quantization_error():
 
 def test_downlink_quantization():
     """Beyond-paper: identity forward, PQ-compressed cotangent backward."""
-    from repro.core.correction import quantize_downlink
-    from repro.core.quantizer import quantize
     z = jax.random.normal(jax.random.PRNGKey(8), (32, 16))
     g_in = jax.random.normal(jax.random.PRNGKey(9), (32, 16))
-    out, vjp = jax.vjp(lambda x: quantize_downlink(x, CFG), z)
+    out, vjp = jax.vjp(
+        lambda x: downlink(x, None, None, PQCompressor(CFG)), z)
     np.testing.assert_array_equal(out, z)          # identity forward
     (g_out,) = vjp(g_in)
     expected = quantize(g_in, CFG).dequantized
@@ -97,7 +101,8 @@ def test_downlink_in_model_trains():
     cfg = get_arch("llama3_8b", smoke=True)
     pq = PQConfig(num_subvectors=cfg.d_model // 8, num_clusters=4,
                   kmeans_iters=3)
-    model = TransformerLM(cfg, pq=pq, lam=1e-4, downlink_pq=pq)
+    model = TransformerLM(cfg, pq=pq, lam=1e-4,
+                          downlink_compressor=PQCompressor(pq))
     params = model.init(jax.random.PRNGKey(0))
     batch = make_lm_batch(jax.random.PRNGKey(1), 2, 32, cfg.vocab_size)
     g = jax.grad(lambda p: model.loss(p, batch)[0])(params)

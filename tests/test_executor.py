@@ -283,11 +283,9 @@ def test_downlink_stateful_cold_matches_stateless():
                                        kmeans_iters=2))
     z = jax.random.normal(jax.random.PRNGKey(0), (12, 64))
     gt = jax.random.normal(jax.random.PRNGKey(1), (12, 64))
-    _, vjp0 = jax.vjp(lambda x: C.compress_downlink(x, comp), z)
-    _, vjp1 = jax.vjp(lambda x: C.compress_downlink_stateful(x, None, comp),
-                      z)
-    np.testing.assert_array_equal(np.asarray(vjp0(gt)[0]),
-                                  np.asarray(vjp1(gt)[0]))
+    _, vjp = jax.vjp(lambda x: C.downlink(x, None, None, comp), z)
+    np.testing.assert_array_equal(np.asarray(comp.compress(gt).recon),
+                                  np.asarray(vjp(gt)[0]))
 
 
 def test_downlink_stateful_warm_uses_state_codebooks():
@@ -303,7 +301,7 @@ def test_downlink_stateful_warm_uses_state_codebooks():
     _, state = quantize_stateful(gref, cfg)
 
     out, vjp = jax.vjp(
-        lambda x, s: C.compress_downlink_stateful(x, s, comp), z, state)
+        lambda x, s: C.downlink(x, None, s, comp), z, state)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(z))  # identity
     gz, gstate = vjp(gt)
     expect = quantize(gt, cfg, state=state).dequantized

@@ -13,8 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import kmeans as km
-from repro.core.compressors import (CutState, PQCompressor,
-                                    compress_with_correction_carry)
+from repro.core.compressors import CutState, PQCompressor, uplink
 from repro.core.quantizer import (PQConfig, QuantizerState, quantize,
                                   quantize_stateful)
 from repro.kernels import ops, ref
@@ -184,8 +183,7 @@ def test_single_kmeans_run_with_carry_hook(monkeypatch):
     state = CutState(quantizer=None, ef_memory=None)
 
     def loss(a):
-        recon, dist, new_state = compress_with_correction_carry(
-            a, 0.5, state, comp)
+        recon, dist, new_state = uplink(a, 0.5, state, comp)
         return jnp.sum(recon ** 2)
 
     val, grad = jax.value_and_grad(loss)(z)
@@ -200,7 +198,7 @@ def test_carry_hook_correction_and_state():
     z = jax.random.normal(jax.random.PRNGKey(10), (16, 16))
     lam = 0.7
     (recon, dist, st1), vjp = jax.vjp(
-        lambda a: compress_with_correction_carry(a, lam, CutState(), comp), z)
+        lambda a: uplink(a, lam, CutState(), comp), z)
     g = jax.random.normal(jax.random.PRNGKey(11), (16, 16))
     (gz,) = vjp((g, jnp.zeros(()), jax.tree.map(jnp.zeros_like, st1)))
     np.testing.assert_allclose(np.asarray(gz),
@@ -208,7 +206,7 @@ def test_carry_hook_correction_and_state():
                                rtol=1e-5, atol=1e-6)
     assert int(st1.quantizer.rounds) == 1
     # warm second round
-    _, _, st2 = compress_with_correction_carry(z, lam, st1, comp)
+    _, _, st2 = uplink(z, lam, st1, comp)
     assert int(st2.quantizer.rounds) == 2
 
 
@@ -222,7 +220,7 @@ def test_carry_hook_error_feedback_telescopes():
     state = CutState(quantizer=None, ef_memory=jnp.zeros((8, 16)))
     sent = jnp.zeros((8, 16))
     for z in zs:
-        recon, _, state = compress_with_correction_carry(z, 0.0, state, comp)
+        recon, _, state = uplink(z, 0.0, state, comp)
         sent = sent + recon
     total = sum(zs)
     np.testing.assert_allclose(np.asarray(sent + state.ef_memory),
